@@ -33,7 +33,7 @@ import json
 import os
 import subprocess
 import sys
-import textwrap
+import time
 
 import numpy as np
 import jax
@@ -188,7 +188,6 @@ def run_speculative(verbose: bool = True, spec_k: int = 4,
     dispatches; at smoke shapes the crossover is ~batch 2).  Feeds the
     ``speculative`` section of ``BENCH_serving.json`` (perf-smoke CI
     tier)."""
-    import time
     from dataclasses import replace
     dcfg = smoke_variant(get(ARCHS[0]))
     tcfg = replace(dcfg, n_layers=target_layers)
@@ -275,7 +274,6 @@ def run_prefix_shared(verbose: bool = True):
     baseline (the matched 48 of 51 prompt tokens are never recomputed,
     so a hit pays one chunk step instead of seven).  Feeds the
     ``prefix`` section of ``BENCH_serving.json`` (perf-smoke CI tier)."""
-    import time
     from repro.serving.telemetry import Telemetry
     cfg = smoke_variant(get(ARCHS[0]))
     params = M.init_params(jax.random.PRNGKey(0), cfg)
@@ -422,7 +420,6 @@ def run_mixed(verbose: bool = True, trace_out: str | None = None):
     histograms supply the TTFT p50/p95/p99, and an identical chunked run
     with telemetry *off* pins ``telemetry_overhead_frac`` (tok/s cost of
     observation; target < 2%) and the on/off bit-identity."""
-    import time
     from repro.serving.telemetry import Telemetry
     cfg = smoke_variant(get(ARCHS[0]))
     params = M.init_params(jax.random.PRNGKey(0), cfg)
@@ -512,7 +509,7 @@ def run_mixed(verbose: bool = True, trace_out: str | None = None):
 
 
 # mixed-length, mixed-priority stream sized so its aggregate page demand
-# is >= 2x the raw pools used below; injected into _SHARDED_BODY too, and
+# is >= 2x the raw pools used below; served by _sharded_result too, and
 # mirrored by tests/test_serving.py's oversubscription tests
 OVERSUB_WORKLOAD = (
     [[i + 1] * (7 + 3 * (i % 3)) for i in range(6)],    # prompts
@@ -596,15 +593,11 @@ def run_oversubscribed(verbose: bool = True, trace_out: str | None = None):
     return out
 
 
-_SHARDED_BODY = """
-    import json, time
-    import numpy as np, jax, jax.numpy as jnp
+def _sharded_result(n_devices: int) -> dict:
+    """Serve the mixed and the oversubscribed streams on ``n_devices``-way
+    data / model meshes of this process's first devices."""
     from jax.sharding import Mesh
-    from repro.configs import get, smoke_variant
-    from repro.models import model as M
-    from repro.runtime.monitor import KVCacheMonitor
-    from repro.serving import EngineConfig, GenerationEngine, Request
-
+    devs = np.array(jax.devices()[:n_devices])
     cfg = smoke_variant(get('qwen3-8b'))
     params = M.init_params(jax.random.PRNGKey(0), cfg)
 
@@ -617,9 +610,9 @@ _SHARDED_BODY = """
 
     def serve(mesh):
         mon = KVCacheMonitor()
-        eng = GenerationEngine(params, cfg, config=EngineConfig(max_batch=4, max_len=64,
-                               page_size=16, compress_cold=True,
-                               kv_monitor=mon, mesh=mesh))
+        eng = GenerationEngine(params, cfg, config=EngineConfig(
+            max_batch=4, max_len=64, page_size=16, compress_cold=True,
+            kv_monitor=mon, mesh=mesh))
         reqs = stream()
         for r in reqs:
             eng.submit(r)
@@ -633,36 +626,30 @@ _SHARDED_BODY = """
 
     out = {}
     _, out['single'] = serve(None)
-    eng, out['data_mesh'] = serve(Mesh(np.array(jax.devices()), ('data',)))
+    eng, out['data_mesh'] = serve(Mesh(devs, ('data',)))
     # data mesh: every slot's pages live on its own shard -> no page bytes
     # ever cross a device for the gather
     out['data_mesh']['cross_shard_gather_bytes_per_step'] = 0
     out['data_mesh']['bit_identical_to_single'] = (
         out['data_mesh'].pop('tokens') == out['single']['tokens'])
     out['single'].pop('tokens')
-    _, out['model_mesh'] = serve(Mesh(np.array(jax.devices()), ('model',)))
+    _, out['model_mesh'] = serve(Mesh(devs, ('model',)))
     out['model_mesh'].pop('tokens')
     # model mesh: pages split round-robin over model shards; each decode
     # step all-gathers (acc, m, l) per attention layer to merge stats
-    n_model = len(jax.devices())
     B, Hq, hd = 4, cfg.n_heads, cfg.hd
     out['model_mesh']['cross_shard_gather_bytes_per_step'] = (
-        eng.paged.n_attn_layers * n_model * (B * Hq * hd * 4 + 2 * B * Hq * 4))
+        eng.paged.n_attn_layers * n_devices
+        * (B * Hq * hd * 4 + 2 * B * Hq * 4))
 
     # oversubscribed + swap on the data mesh: aggregate page demand >= 2x
     # the raw pool, per-shard free lists + per-shard swap ledgers, tokens
     # still bit-identical to the single-device monolithic reference
-    def oversub_reqs():
-        prompts, news, prios = __OVERSUB_WORKLOAD__
-        return [Request(prompt=p, max_new_tokens=n, priority=pr,
-                        id=10_000 + i)
-                for i, (p, n, pr) in enumerate(zip(prompts, news, prios))]
-
     def serve_over(mesh, **kw):
         mon = KVCacheMonitor()
-        eng = GenerationEngine(params, cfg, config=EngineConfig(max_batch=4, max_len=48,
-                               kv_monitor=mon, mesh=mesh, **kw))
-        reqs = oversub_reqs()
+        eng = GenerationEngine(params, cfg, config=EngineConfig(
+            max_batch=4, max_len=48, kv_monitor=mon, mesh=mesh, **kw))
+        reqs = _oversub_stream()
         for r in reqs:
             eng.submit(r)
         eng.run()
@@ -671,12 +658,12 @@ _SHARDED_BODY = """
 
     mono_o, _, _ = serve_over(None, cache_mode='monolithic')
     toks_o, eng_o, mon_o = serve_over(
-        Mesh(np.array(jax.devices()), ('data',)), cache_mode='paged',
+        Mesh(devs, ('data',)), cache_mode='paged',
         page_size=8, n_pages=8, compress_cold=True, n_cold_slots=2,
         swap_bytes=1 << 28)
     demand = sum(eng_o.paged.pages_worst_case(len(r.prompt),
                                               r.max_new_tokens)
-                 for r in oversub_reqs())
+                 for r in _oversub_stream())
     assert demand >= 2 * eng_o.paged.n_pages, (demand, eng_o.paged.n_pages)
     s_o = mon_o.summary()
     assert toks_o == mono_o
@@ -689,25 +676,38 @@ _SHARDED_BODY = """
         'swap_in_bytes': s_o['swap_in_bytes_total'],
         'bit_identical_to_single': True,
     }
-    print('RESULT ' + json.dumps(out))
-"""
+    return out
 
 
 def run_sharded(n_devices: int = 2, verbose: bool = True):
-    """Serve the mixed stream on 2-way data / model meshes (subprocess with
-    ``--xla_force_host_platform_device_count``, keeping this process at 1
-    device) and report pages-per-shard + cross-shard gather cost."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(repo, "src"),
-               XLA_FLAGS=f"--xla_force_host_platform_device_count"
-                         f"={n_devices}")
-    body = textwrap.dedent(_SHARDED_BODY).replace(
-        "__OVERSUB_WORKLOAD__", repr(OVERSUB_WORKLOAD))
-    p = subprocess.run([sys.executable, "-c", body],
-                       env=env, capture_output=True, text=True, timeout=900)
-    assert p.returncode == 0, f"sharded bench failed:\n{p.stderr[-4000:]}"
-    out = json.loads(p.stdout.strip().splitlines()[-1].removeprefix("RESULT "))
+    """Serve the mixed stream on 2-way data / model meshes and report
+    pages-per-shard + cross-shard gather cost.
+
+    Where this process already sees ``n_devices`` accelerator devices it
+    runs on them in-process (a child could not reach chips this process
+    holds).  Otherwise it runs in a child with that many virtual CPU
+    devices (``--xla_force_host_platform_device_count``), keeping this
+    process at its own device count."""
+    if (jax.default_backend() != "cpu"
+            and len(jax.devices()) >= n_devices):
+        out = _sharded_result(n_devices)
+    else:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ,
+                   PYTHONPATH=os.pathsep.join(
+                       [repo, os.path.join(repo, "src")]),
+                   JAX_PLATFORMS="cpu",
+                   XLA_FLAGS=f"--xla_force_host_platform_device_count"
+                             f"={n_devices}")
+        body = ("import json; from benchmarks.kvcache_bench import "
+                f"_sharded_result; print('RESULT ' + json.dumps("
+                f"_sharded_result({n_devices})))")
+        p = subprocess.run([sys.executable, "-c", body], env=env,
+                           capture_output=True, text=True, timeout=900)
+        assert p.returncode == 0, \
+            f"sharded bench failed:\n{p.stderr[-4000:]}"
+        out = json.loads(
+            p.stdout.strip().splitlines()[-1].removeprefix("RESULT "))
     assert out["data_mesh"]["bit_identical_to_single"]
     if verbose:
         print(f"\nsharded engine (qwen3-8b smoke, batch 4, {n_devices} "

@@ -10,7 +10,10 @@ concentration the paper measures for weights holds for K/V activations
 The demo queues a mixed-length request stream through a small batch,
 proves the paged+compressed path emits the exact tokens of the
 monolithic baseline, and prints raw-vs-compressed cache bytes and
-throughput.  Runs on CPU (interpret mode), no TPU required.
+throughput.  On the CPU (``JAX_PLATFORMS=cpu``) the Pallas kernels run in
+interpret mode; on a TPU the same script runs them compiled (its
+wall-clock figures are host timings either way, not device metrics).
+``python chip_smoke.py`` is the full-width run on a chip.
 
 Usage:  PYTHONPATH=src python examples/serve_lm.py
 """

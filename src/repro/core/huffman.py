@@ -180,17 +180,8 @@ class Codebook:
         codes = self.codes[symbols]
         total_bits = int(lens.sum())
         ends = np.cumsum(lens)
-        starts = ends - lens
-        nbytes = (total_bits + 7) // 8
-        out = np.zeros(nbytes, dtype=np.uint8)
-        # vectorized bit blit: expand each code into its bits
-        if total_bits:
-            bit_idx = np.repeat(starts, lens) + _concat_aranges(lens)
-            shift = np.repeat(lens, lens) - 1 - _concat_aranges(lens)
-            bits = (np.repeat(codes, lens) >> shift) & 1
-            np.bitwise_or.at(
-                out, bit_idx // 8, (bits << (7 - bit_idx % 8)).astype(np.uint8)
-            )
+        out = pack_codes(codes[None], lens[None], (ends - lens)[None],
+                         (total_bits + 7) // 8, self.max_len)[0]
         return out, total_bits
 
     def decode_bitstream(self, data: np.ndarray, n_symbols: int,
@@ -213,9 +204,28 @@ class Codebook:
         return out
 
 
-def _concat_aranges(lens: np.ndarray) -> np.ndarray:
-    """[arange(l) for l in lens], concatenated (vectorized)."""
-    total = int(lens.sum())
-    ids = np.arange(total)
-    starts = np.repeat(np.cumsum(lens) - lens, lens)
-    return ids - starts
+def pack_codes(codes: np.ndarray, lens: np.ndarray, starts: np.ndarray,
+               stride: int, max_len: int) -> np.ndarray:
+    """Blit (R, S) codes of at most ``max_len`` bits, MSB first at bit
+    ``starts`` of their row, into (R, stride) bytes.
+
+    A code starting at bit ``o`` of byte ``b`` spans bytes ``b`` up to
+    ``b + nb - 1`` (``nb`` bytes hold ``o + max_len <= 7 + max_len``
+    bits); placed in an ``8 * nb``-bit window it contributes one byte of
+    the window to each.  Codes of one row never share a bit, so summing
+    the contributions per byte (``bincount``) equals OR-ing them.  Bytes
+    past a row's codes are zero, so a spill into the next row's first
+    bytes adds nothing.
+    """
+    R = codes.shape[0]
+    nb = (max_len + 14) // 8
+    n = R * stride + nb
+    idx = np.int32 if n < 2 ** 31 else np.int64
+    byte = (np.arange(R, dtype=idx)[:, None] * idx(stride)
+            + (starts >> 3)).reshape(-1)
+    win = (codes << (8 * nb - lens - (starts & 7))).reshape(-1)
+    out = np.zeros(n, np.float64)
+    for j in range(nb):
+        out += np.bincount(byte + j, minlength=n,
+                           weights=(win >> (8 * (nb - 1 - j))) & 0xFF)
+    return out[: R * stride].astype(np.uint8).reshape(R, stride)

@@ -16,8 +16,9 @@ lockstep:
 
 Two payload layouts:
   * ``uniform``: all chunks padded to the tensor-wide max lane stride —
-    shape ``(C, stride, 128)``; decodable fully in parallel with plain jnp
-    (used in-graph by serve steps on any backend);
+    shape ``(C, stride, 128)``; decoded in-graph by serve steps, on a TPU
+    by the Pallas kernel (``kernels/ecf8_decode.py``, one chunk per grid
+    cell), elsewhere fully in parallel with plain jnp;
   * ``ragged``: per-chunk strides + offsets — denser; consumed by the Pallas
     kernel via scalar-prefetch indexed blocks.
 
@@ -25,6 +26,8 @@ Both are bit-exact; the uniform padding tax is reported by benchmarks.
 """
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from functools import partial
 
@@ -33,12 +36,14 @@ import jax
 import jax.numpy as jnp
 
 from . import fp8
-from .huffman import Codebook
+from .huffman import Codebook, pack_codes
 
 LANES = 128
 DEFAULT_SYM_PER_LANE = 256
 MAX_CODE_LEN = 8
 MIN_STRIDE = 4  # decode window preloads 4 bytes
+_ENCODE_GROUP = 32           # chunks per host encode task (1M symbols)
+_ENCODE_THREADS = min(8, os.cpu_count() or 1)
 
 
 @dataclass
@@ -90,7 +95,7 @@ def encode(weight_bits: np.ndarray,
     orig_shape = tuple(weight_bits.shape)
     flat = np.asarray(weight_bits, dtype=np.uint8).reshape(-1)
     n = flat.shape[0]
-    exps = fp8.exponent_field(flat, xp=np).astype(np.int64)
+    exps = fp8.exponent_field(flat, xp=np).astype(np.uint8)
     signmant = fp8.signmant_nibble(flat, xp=np)
 
     freqs = np.bincount(exps, minlength=16)
@@ -102,55 +107,30 @@ def encode(weight_bits: np.ndarray,
     chunk_sym = LANES * S
     n_pad = -n % chunk_sym
     pad_sym = int(np.argmax(freqs))
-    exps_p = np.concatenate([exps, np.full(n_pad, pad_sym, dtype=np.int64)])
+    exps_p = np.concatenate([exps, np.full(n_pad, pad_sym, dtype=np.uint8)])
     C = exps_p.shape[0] // chunk_sym
 
-    # element (c, s, l) -> index c*chunk_sym + s*LANES + l
-    exps_csl = exps_p.reshape(C, S, LANES)
-    codes = cb.codes[exps_csl]                    # (C, S, L) int64
-    lens = cb.lengths[exps_csl].astype(np.int64)  # (C, S, L)
-
-    # per-lane streams: rows = (c, l), S symbols each
-    codes_r = codes.transpose(0, 2, 1).reshape(C * LANES, S)
-    lens_r = lens.transpose(0, 2, 1).reshape(C * LANES, S)
-    starts_r = np.cumsum(lens_r, axis=1) - lens_r
-    lane_bits = starts_r[:, -1] + lens_r[:, -1]          # (C*L,)
-    lane_bytes = (lane_bits + 7) // 8
-    stride_per_chunk = np.maximum(
-        lane_bytes.reshape(C, LANES).max(axis=1), MIN_STRIDE
-    ).astype(np.int64)
+    # chunks code independently under the tensor's one codebook: pack
+    # groups of them on a thread pool (numpy releases the GIL)
+    groups = [(c0, min(c0 + _ENCODE_GROUP, C))
+              for c0 in range(0, C, _ENCODE_GROUP)]
+    pack = partial(_encode_chunks, exps_p, cb=cb, sym_per_lane=S)
+    if len(groups) > 1:
+        with ThreadPoolExecutor(min(len(groups), _ENCODE_THREADS)) as ex:
+            parts = list(ex.map(pack, groups))
+    else:
+        parts = [pack(groups[0])]
+    stride_per_chunk = np.concatenate([sc for _, sc in parts])
     stride = int(stride_per_chunk.max())
-
-    # vectorized bit blit into (C*L, stride*8) bit matrix
-    flat_lens = lens_r.reshape(-1)
-    total_bits = int(flat_lens.sum())
-    rep_rows = np.repeat(
-        np.repeat(np.arange(C * LANES), S), flat_lens
-    )
-    within = _concat_aranges(flat_lens)
-    bitpos = np.repeat(starts_r.reshape(-1), flat_lens) + within
-    shift = np.repeat(flat_lens, flat_lens) - 1 - within
-    bitvals = (np.repeat(codes_r.reshape(-1), flat_lens) >> shift) & 1
-    bitmat = np.zeros((C * LANES, stride * 8), dtype=np.uint8)
-    bitmat[rep_rows, bitpos] = bitvals.astype(np.uint8)
-
-    weights = (1 << np.arange(7, -1, -1)).astype(np.uint16)
-    bytemat = (
-        bitmat.reshape(C * LANES, stride, 8).astype(np.uint16) * weights
-    ).sum(axis=2).astype(np.uint8)                        # (C*L, stride)
-    payload = bytemat.reshape(C, LANES, stride).transpose(0, 2, 1).copy()
+    payload = np.zeros((C, stride, LANES), np.uint8)
+    for (c0, c1), (pay, _) in zip(groups, parts):
+        payload[c0:c1, : pay.shape[1]] = pay
 
     # ragged layout: per-chunk stride_c slices
     offsets = np.zeros(C + 1, dtype=np.int64)
-    ragged_parts = []
-    for c in range(C):
-        sc = int(stride_per_chunk[c])
-        ragged_parts.append(payload[c, :sc, :].reshape(-1))
-        offsets[c + 1] = offsets[c] + sc * LANES
-    payload_ragged = (
-        np.concatenate(ragged_parts) if ragged_parts
-        else np.zeros(0, dtype=np.uint8)
-    )
+    offsets[1:] = np.cumsum(stride_per_chunk * LANES)
+    payload_ragged = payload[
+        np.arange(stride)[None, :] < stride_per_chunk[:, None]].reshape(-1)
 
     return TpuECF8(
         payload=payload,
@@ -192,15 +172,40 @@ def decode_ref(c: TpuECF8) -> np.ndarray:
     return _assemble(c, syms.reshape(-1)[: c.n_elem])
 
 
+def signmant_rows(signmant: np.ndarray, n_chunks: int,
+                  sym_per_lane: int) -> np.ndarray:
+    """Nibble-packed sign/mantissa plane in element order (two elements
+    per byte) -> (C, ceil(S / 2), LANES) uint8 *row layout*: row ``r`` of
+    chunk ``c`` holds slot ``2r`` in the high and slot ``2r + 1`` in the
+    low nibble, lane by lane.
+
+    Host-side (numpy).  Both on-device decoders read this layout — one
+    row per two decode rounds, lane-dense — so no decode ever spreads
+    bytes across lanes (on a TPU a trailing dim of 2 pads to 128 lanes,
+    a 64x blow-up at checkpoint sizes)."""
+    C, S = n_chunks, sym_per_lane
+    b = np.zeros(C * S * LANES // 2, np.uint8)
+    b[: signmant.shape[0]] = signmant
+    b = b.reshape(C, S, LANES // 2)
+    nib = np.stack([b >> 4, b & 0xF], axis=-1).reshape(C, S, LANES)
+    if S % 2:
+        nib = np.pad(nib, ((0, 0), (0, 1), (0, 0)))
+    nib = nib.reshape(C, -1, 2, LANES)
+    return (nib[:, :, 0] << 4) | nib[:, :, 1]
+
+
 @partial(jax.jit, static_argnames=("sym_per_lane", "n_elem"))
-def _decode_jnp_impl(payload, signmant, lj_limit, first_lj, offset, perm,
+def _decode_jnp_impl(payload, sm_rows, lj_limit, first_lj, offset, perm,
                      sym_per_lane: int, n_elem: int):
     """Vectorized decode of the uniform layout; all chunks in parallel.
 
-    Maintains a per-lane left-aligned uint32 bit window; each round does the
-    canonical compare/select decode on the top 8 bits, shifts, and refills at
-    most one byte via a per-lane gather (take_along_axis).  Invariant: at the
-    top of each round ``bits_valid >= 24 >= 8``.
+    ``sm_rows`` is the sign/mantissa plane in :func:`signmant_rows`
+    layout.  Maintains a per-lane left-aligned uint32 bit window; each
+    round does the canonical compare/select decode on the top 8 bits,
+    fuses the slot's sign/mantissa nibble into the fp8 byte, shifts, and
+    refills at most one byte via a per-lane gather (take_along_axis).
+    Invariant: at the top of each round ``bits_valid >= 24 >= 8``.
+    Returns the (n_elem,) uint8 fp8 bit view.
     """
     C, stride, L = payload.shape
     S = sym_per_lane
@@ -217,16 +222,20 @@ def _decode_jnp_impl(payload, signmant, lj_limit, first_lj, offset, perm,
     offset_i = offset.astype(jnp.int32)
     perm_i = perm.astype(jnp.int32)
 
-    def round_fn(_, carry):
-        win, byteptr, bits_valid, outs, s = carry
+    def round_fn(s, carry):
+        win, byteptr, bits_valid, outs = carry
         peek = (win >> 24).astype(jnp.int32)    # (C, L) in [0, 256)
         lt = peek[..., None] < lj_limit_i[None, None, :]   # (C, L, 8)
         length = jnp.argmax(lt, axis=-1).astype(jnp.int32) + 1
         fl = jnp.take(first_lj_i, length - 1)
         off = jnp.take(offset_i, length - 1)
         sym_idx = off + ((peek - fl) >> (8 - length))
-        sym = jnp.take(perm_i, sym_idx).astype(jnp.uint8)
-        outs = jax.lax.dynamic_update_index_in_dim(outs, sym, s, axis=1)
+        sym = jnp.take(perm_i, sym_idx)
+        row = jax.lax.dynamic_index_in_dim(sm_rows, s // 2, axis=1,
+                                           keepdims=False).astype(jnp.int32)
+        sm = (row >> (4 * (1 - s % 2))) & 0xF
+        byte = fp8.assemble(sym, sm, xp=jnp)
+        outs = jax.lax.dynamic_update_index_in_dim(outs, byte, s, axis=1)
 
         win = win << length.astype(jnp.uint32)
         bits_valid = bits_valid - length
@@ -240,21 +249,20 @@ def _decode_jnp_impl(payload, signmant, lj_limit, first_lj, offset, perm,
         )
         byteptr = byteptr + need.astype(jnp.int32)
         bits_valid = bits_valid + 8 * need.astype(jnp.int32)
-        return win, byteptr, bits_valid, outs, s + 1
+        return win, byteptr, bits_valid, outs
 
     outs = jnp.zeros((C, S, L), dtype=jnp.uint8)
-    _, _, _, outs, _ = jax.lax.fori_loop(
-        0, S, round_fn, (win, byteptr, bits_valid, outs, 0)
+    _, _, _, outs = jax.lax.fori_loop(
+        0, S, round_fn, (win, byteptr, bits_valid, outs)
     )
-    syms = outs.reshape(-1)[:n_elem]
-    sm = fp8.unpack_nibbles(signmant, n_elem, xp=jnp)
-    return fp8.assemble(syms, sm, xp=jnp)
+    return outs.reshape(-1)[:n_elem]
 
 
 def decode_jnp(c: TpuECF8) -> jnp.ndarray:
     """In-graph decode of the uniform layout -> uint8 fp8 bits (n_elem,)."""
     return _decode_jnp_impl(
-        jnp.asarray(c.payload), jnp.asarray(c.signmant),
+        jnp.asarray(c.payload),
+        jnp.asarray(signmant_rows(c.signmant, c.num_chunks, c.sym_per_lane)),
         jnp.asarray(c.lj_limit), jnp.asarray(c.first_lj),
         jnp.asarray(c.offset), jnp.asarray(c.perm),
         sym_per_lane=c.sym_per_lane, n_elem=c.n_elem,
@@ -276,8 +284,27 @@ def _assemble(c: TpuECF8, syms: np.ndarray) -> np.ndarray:
     return fp8.assemble(syms.astype(np.uint8), sm, xp=np).reshape(c.shape)
 
 
-def _concat_aranges(lens: np.ndarray) -> np.ndarray:
-    total = int(lens.sum())
-    ids = np.arange(total)
-    starts = np.repeat(np.cumsum(lens) - lens, lens)
-    return ids - starts
+def _encode_chunks(exps_p: np.ndarray, group: tuple[int, int], *,
+                   cb: Codebook, sym_per_lane: int):
+    """Code chunks ``[c0, c1)`` of the padded exponent plane ->
+    ((c1 - c0, stride_g, LANES) payload at the group's own max stride,
+    (c1 - c0,) per-chunk strides)."""
+    c0, c1 = group
+    S = sym_per_lane
+    G = c1 - c0
+    # element (c, s, l) -> index c*chunk_sym + s*LANES + l; per-lane
+    # streams: rows = (c, l), S symbols each
+    exps_r = exps_p[c0 * S * LANES: c1 * S * LANES].reshape(
+        G, S, LANES).transpose(0, 2, 1).reshape(G * LANES, S)
+    codes_r = cb.codes.astype(np.int32)[exps_r]           # (G*L, S)
+    lens_r = cb.lengths.astype(np.int32)[exps_r]
+    ends_r = np.cumsum(lens_r, axis=1, dtype=np.int32)
+    lane_bytes = (ends_r[:, -1] + 7) // 8                 # (G*L,)
+    stride_per_chunk = np.maximum(
+        lane_bytes.reshape(G, LANES).max(axis=1), MIN_STRIDE
+    ).astype(np.int64)
+    stride = int(stride_per_chunk.max())
+    bytemat = pack_codes(codes_r, lens_r, ends_r - lens_r, stride,
+                         MAX_CODE_LEN)
+    return (bytemat.reshape(G, LANES, stride).transpose(0, 2, 1),
+            stride_per_chunk)

@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels of the ECF8 decode family, with jnp oracles
+(``ref.py``) and backend-dispatching entry points (``ops.py``)."""
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: compiled (Mosaic) on a
+    TPU backend, interpreted on every other backend.
+
+    The one place every kernel entry point resolves ``interpret=None``."""
+    return jax.default_backend() != "tpu"

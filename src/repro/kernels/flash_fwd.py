@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 F32 = jnp.float32
 NEG = -1e30
 
@@ -45,10 +47,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, causal: bool,
 
     def body(ki, carry):
         acc, m, l = carry
-        k_blk = pl.load(k_ref, (0, pl.dslice(ki * bk, bk),
-                                slice(None))).astype(F32)   # (bk, D)
-        v_blk = pl.load(v_ref, (0, pl.dslice(ki * bk, bk),
-                                slice(None))).astype(F32)
+        k_blk = k_ref[0, pl.ds(ki * bk, bk), :].astype(F32)   # (bk, D)
+        v_blk = v_ref[0, pl.ds(ki * bk, bk), :].astype(F32)
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=F32)  # (bq, bk)
         if softcap:
@@ -78,10 +78,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, causal: bool,
     "causal", "softcap", "bq", "bk", "q_base", "interpret"))
 def flash_fwd_pallas(q, k, v, *, causal: bool = True, softcap: float = 0.0,
                      bq: int = 256, bk: int = 512, q_base: int = 0,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """q: (B, Hq, Tq, D), k/v: (B, Hkv, Tk, D) -> o (B, Hq, Tq, D).
 
-    Tq must divide by bq and Tk by bk (the model pads its inputs)."""
+    Tq must divide by bq and Tk by bk (the model pads its inputs).
+    ``interpret=None`` picks Pallas interpret mode from the backend
+    (:func:`repro.kernels.interpret_mode`)."""
     B, Hq, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     g = Hq // Hkv
@@ -107,6 +109,6 @@ def flash_fwd_pallas(q, k, v, *, causal: bool = True, softcap: float = 0.0,
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Tq, D), v.dtype),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(qf, kf, vf)
     return out.reshape(B, Hq, Tq, D)

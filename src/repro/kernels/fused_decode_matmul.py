@@ -28,9 +28,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import fp8 as fp8mod
 from repro.core import tpu_format
 from repro.core.tpu_format import LANES, MAX_CODE_LEN
+from repro.kernels import interpret_mode
+from repro.kernels.ecf8_decode import decode_tables
 
 
 @dataclass
@@ -38,7 +39,7 @@ class TiledECF8Weight:
     """(K, N) fp8 weight in fused-GEMM tile order (host-side arrays)."""
 
     payload: np.ndarray   # (TK, TN, stride, LANES) uint8
-    signmant: np.ndarray  # (TK, TN, S * LANES // 2) uint8
+    signmant: np.ndarray  # (TK, TN, ceil(S / 2), LANES) uint8 nibble rows
     lj_limit: np.ndarray  # (8,) int32
     first_lj: np.ndarray
     offset: np.ndarray
@@ -66,33 +67,45 @@ def encode_tiled(w_bits: np.ndarray,
     c = tpu_format.encode(perm_elems, sym_per_lane=S)
     C, stride, _ = c.payload.shape
     assert C == TK * TN
-    total_sm = C * S * LANES // 2
-    sm = np.zeros(total_sm, dtype=np.uint8)
-    sm[: c.signmant.shape[0]] = c.signmant
+    rows = tpu_format.signmant_rows(c.signmant, C, S)
     return TiledECF8Weight(
         payload=np.asarray(c.payload).reshape(TK, TN, stride, LANES),
-        signmant=sm.reshape(TK, TN, S * LANES // 2),
+        signmant=rows.reshape(TK, TN, -1, LANES),
         lj_limit=c.lj_limit, first_lj=c.first_lj, offset=c.offset,
         perm=c.perm, k=K, n=N, sym_per_lane=S,
     )
 
 
-def _fused_kernel(limit_ref, first_ref, offset_ref, perm_ref, x_ref,
-                  payload_ref, signmant_ref, out_ref, w_scratch, *,
-                  sym_per_lane: int, stride: int, n_tk: int):
+def _fp8_row_f32(sym, sm):
+    """Decoded e4m3fn fields -> exact float32 values, by bit assembly
+    (the TPU has no fp8 unit on v5e): ``sym`` the 4-bit exponent, ``sm``
+    the sign/mantissa nibble, both int32 of one shape."""
+    sign = (sm & 8) << 28
+    mant = sm & 7
+    normal = sign | ((sym + 120) << 23) | (mant << 20)
+    subnormal = sign | jax.lax.bitcast_convert_type(
+        mant.astype(jnp.float32) * (2.0 ** -9), jnp.int32)
+    nan = sign | 0x7FC00000
+    bits = jnp.where(sym == 0, subnormal,
+                     jnp.where((sym == 15) & (mant == 7), nan, normal))
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _fused_kernel(tab_ref, x_ref, payload_ref, sm_ref, out_ref, sm_scr,
+                  w_scratch, *, sym_per_lane: int, stride: int):
     S = sym_per_lane
     tk = pl.program_id(1)
-    payload = payload_ref[0, 0].astype(jnp.uint32)     # (stride, L)
+    lims = [tab_ref[i] for i in range(MAX_CODE_LEN)]
+    firsts = [tab_ref[MAX_CODE_LEN + i] for i in range(MAX_CODE_LEN)]
+    offs = [tab_ref[2 * MAX_CODE_LEN + i] for i in range(MAX_CODE_LEN)]
+    perm = [tab_ref[3 * MAX_CODE_LEN + k] for k in range(16)]
+    payload = payload_ref[0, 0].astype(jnp.int32)     # (stride, L)
 
     win = ((payload[0:1, :] << 24) | (payload[1:2, :] << 16)
-           | (payload[2:3, :] << 8) | payload[3:4, :])
+           | (payload[2:3, :] << 8) | payload[3:4, :]).astype(jnp.uint32)
     byteptr = jnp.full((1, LANES), 4, dtype=jnp.int32)
     bits_valid = jnp.full((1, LANES), 32, dtype=jnp.int32)
-
-    smp = signmant_ref[0, 0].reshape(S, LANES // 2)
-    sm_hi = (smp >> 4) & jnp.uint8(0x0F)
-    sm_lo = smp & jnp.uint8(0x0F)
-    sm = jnp.stack([sm_hi, sm_lo], axis=-1).reshape(S, LANES).astype(jnp.int32)
+    sm_scr[...] = sm_ref[0, 0].astype(jnp.int32)      # (ceil(S/2), L)
 
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (stride, LANES), 0)
 
@@ -103,30 +116,25 @@ def _fused_kernel(limit_ref, first_ref, offset_ref, perm_ref, x_ref,
         sym_idx = jnp.zeros((1, LANES), jnp.int32)
         found = jnp.zeros((1, LANES), jnp.bool_)
         for l in range(1, MAX_CODE_LEN + 1):
-            cond = jnp.logical_and(peek < limit_ref[0, l - 1],
-                                   jnp.logical_not(found))
-            idx_l = offset_ref[0, l - 1] + (
-                (peek - first_ref[0, l - 1]) >> (8 - l)
-            )
+            cond = jnp.logical_and(peek < lims[l - 1], jnp.logical_not(found))
+            idx_l = offs[l - 1] + ((peek - firsts[l - 1]) >> (8 - l))
             length = jnp.where(cond, l, length)
             sym_idx = jnp.where(cond, idx_l, sym_idx)
             found = jnp.logical_or(found, cond)
         sym = jnp.zeros((1, LANES), jnp.int32)
         for k in range(16):
-            sym = jnp.where(sym_idx == k, perm_ref[0, k], sym)
+            sym = jnp.where(sym_idx == k, perm[k], sym)
 
-        sm_s = jax.lax.dynamic_slice_in_dim(sm, s, 1, axis=0)
-        byte = ((sm_s & 8) << 4) | (sym << 3) | (sm_s & 7)
-        w_row = byte.astype(jnp.uint8).view(fp8mod.FP8_DTYPE).astype(
-            jnp.bfloat16)
-        pl.store(w_scratch, (pl.dslice(s, 1), slice(None)), w_row)
+        sm_row = sm_scr[pl.ds(s // 2, 1), :]
+        sm_s = (sm_row >> (4 * (1 - s % 2))) & 0xF
+        w_scratch[pl.ds(s, 1), :] = _fp8_row_f32(sym, sm_s)
 
         win = win << length.astype(jnp.uint32)
         bits_valid = bits_valid - length
         need = bits_valid <= 24
         safe_ptr = jnp.minimum(byteptr, stride - 1)
-        nb = jnp.sum(jnp.where(row_iota == safe_ptr, payload, jnp.uint32(0)),
-                     axis=0, keepdims=True)
+        nb = jnp.sum(jnp.where(row_iota == safe_ptr, payload, 0),
+                     axis=0, keepdims=True).astype(jnp.uint32)
         win = jnp.where(need,
                         win | (nb << (24 - bits_valid).astype(jnp.uint32)),
                         win)
@@ -141,7 +149,7 @@ def _fused_kernel(limit_ref, first_ref, offset_ref, perm_ref, x_ref,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     out_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.bfloat16), w_scratch[...],
+        x_ref[...].astype(jnp.bfloat16), w_scratch[...].astype(jnp.bfloat16),
         preferred_element_type=jnp.float32,
     )
 
@@ -155,44 +163,42 @@ def _matmul_impl(x, payload, signmant, lj_limit, first_lj, offset, perm, *,
     M = x.shape[0]
     S = sym_per_lane
     TK, TN, stride, _ = payload.shape
-    kernel = functools.partial(_fused_kernel, sym_per_lane=S, stride=stride,
-                               n_tk=TK)
+    R = signmant.shape[2]
+    kernel = functools.partial(_fused_kernel, sym_per_lane=S, stride=stride)
     out = pl.pallas_call(
         kernel,
-        grid=(TN, TK),
-        in_specs=[
-            pl.BlockSpec((1, 8), lambda tn, tk: (0, 0)),
-            pl.BlockSpec((1, 8), lambda tn, tk: (0, 0)),
-            pl.BlockSpec((1, 8), lambda tn, tk: (0, 0)),
-            pl.BlockSpec((1, 16), lambda tn, tk: (0, 0)),
-            pl.BlockSpec((M, S), lambda tn, tk: (0, tk)),          # x
-            pl.BlockSpec((1, 1, stride, LANES),
-                         lambda tn, tk: (tk, tn, 0, 0)),           # payload
-            pl.BlockSpec((1, 1, S * LANES // 2),
-                         lambda tn, tk: (tk, tn, 0)),              # signmant
-        ],
-        out_specs=pl.BlockSpec((M, LANES), lambda tn, tk: (0, tn)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(TN, TK),
+            in_specs=[
+                pl.BlockSpec((M, S), lambda tn, tk, tab: (0, tk)),     # x
+                pl.BlockSpec((1, 1, stride, LANES),
+                             lambda tn, tk, tab: (tk, tn, 0, 0)),     # payload
+                pl.BlockSpec((1, 1, R, LANES),
+                             lambda tn, tk, tab: (tk, tn, 0, 0)),     # sm rows
+            ],
+            out_specs=pl.BlockSpec((M, LANES), lambda tn, tk, tab: (0, tn)),
+            scratch_shapes=[pltpu.VMEM((R, LANES), jnp.int32),
+                            pltpu.VMEM((S, LANES), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((M, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((S, LANES), jnp.bfloat16)],
         interpret=interpret,
-    )(
-        lj_limit.reshape(1, 8).astype(jnp.int32),
-        first_lj.reshape(1, 8).astype(jnp.int32),
-        offset.reshape(1, 8).astype(jnp.int32),
-        perm.reshape(1, 16).astype(jnp.int32),
-        x, payload, signmant,
-    )
+    )(decode_tables(lj_limit, first_lj, offset, perm), x, payload, signmant)
     return out.astype(out_dtype)
 
 
 def matmul_pallas(x, w: TiledECF8Weight, *, out_dtype=jnp.float32,
-                  interpret: bool = True):
-    """y = x @ decode(W); x: (M, K) with M <= 512 (decode-GEMM regime)."""
+                  interpret: bool | None = None):
+    """y = x @ decode(W); x: (M, K) with M <= 512 (decode-GEMM regime).
+
+    ``interpret=None`` picks Pallas interpret mode from the backend
+    (:func:`repro.kernels.interpret_mode`)."""
     assert x.shape[1] == w.k, (x.shape, w.k)
     return _matmul_impl(
         jnp.asarray(x), jnp.asarray(w.payload), jnp.asarray(w.signmant),
         jnp.asarray(w.lj_limit), jnp.asarray(w.first_lj),
         jnp.asarray(w.offset), jnp.asarray(w.perm),
-        sym_per_lane=w.sym_per_lane, k=w.k, n=w.n, interpret=interpret,
+        sym_per_lane=w.sym_per_lane, k=w.k, n=w.n,
+        interpret=interpret_mode() if interpret is None else interpret,
         out_dtype=out_dtype,
     )

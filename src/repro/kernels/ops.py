@@ -1,44 +1,30 @@
 """Jitted entry points for the Pallas kernels, with backend dispatch.
 
-On TPU the compiled Pallas kernels run natively; elsewhere (this CPU
-container, and any backend without Mosaic) the same kernel bodies execute in
-``interpret=True`` mode, and large in-graph users (serve steps) fall back to
-the algebraically-identical jnp implementations in ``core``/``ref``.
+On TPU the compiled Pallas kernels run natively; on any other backend the
+same kernel bodies execute in Pallas interpret mode
+(:func:`repro.kernels.interpret_mode` decides, for every entry point).
 """
 from __future__ import annotations
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 
-from repro.core import fp8, tpu_format
-from repro.core.tpu_format import LANES
+from repro.core import tpu_format
 from . import ecf8_decode as _dec
 from . import fused_decode_matmul as _fused
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def decode_tpu_format(container: tpu_format.TpuECF8,
-                      force_pallas: bool | None = None) -> np.ndarray:
+                      interpret: bool | None = None) -> np.ndarray:
     """Decode an ECF8-TPU container with the Pallas kernel -> fp8 bits (N,).
 
-    ``force_pallas=None`` picks native Pallas on TPU, interpret elsewhere.
+    ``interpret=None`` picks compiled Pallas on TPU, interpret elsewhere.
     """
-    C, stride, _ = container.payload.shape
+    C = container.num_chunks
     S = container.sym_per_lane
-    interpret = not _on_tpu() if force_pallas is None else not force_pallas
-
-    # per-chunk signmant bytes (pad tail to rectangle)
-    total = C * S * LANES // 2
-    sm = np.zeros(total, dtype=np.uint8)
-    sm[: container.signmant.shape[0]] = container.signmant
-    sm = sm.reshape(C, S * LANES // 2)
-
     out = _dec.decode_pallas(
-        jnp.asarray(container.payload), jnp.asarray(sm),
+        jnp.asarray(container.payload),
+        jnp.asarray(tpu_format.signmant_rows(container.signmant, C, S)),
         jnp.asarray(container.lj_limit), jnp.asarray(container.first_lj),
         jnp.asarray(container.offset), jnp.asarray(container.perm),
         sym_per_lane=S, interpret=interpret,
@@ -46,9 +32,8 @@ def decode_tpu_format(container: tpu_format.TpuECF8,
     return np.asarray(out).reshape(-1)[: container.n_elem]
 
 
-def fused_decode_matmul(x, tiled, *, force_pallas: bool | None = None,
+def fused_decode_matmul(x, tiled, *, interpret: bool | None = None,
                         out_dtype=jnp.float32):
-    """``x @ decode(W)`` with W in tiled ECF8-FR form (see the kernel)."""
-    interpret = not _on_tpu() if force_pallas is None else not force_pallas
+    """``x @ decode(W)`` with W in tiled ECF8 form (see the kernel)."""
     return _fused.matmul_pallas(x, tiled, out_dtype=out_dtype,
                                 interpret=interpret)

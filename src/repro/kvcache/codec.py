@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import fp8
-from repro.core.huffman import Codebook, _concat_aranges
+from repro.core.huffman import Codebook, pack_codes
 
 LANES = 128
 MIN_STRIDE = 4          # decode window preloads 4 bytes
@@ -204,25 +204,12 @@ def _encode_lanes(syms: np.ndarray, cb: Codebook) -> np.ndarray:
 
     Element ``i`` maps to lane ``i % LANES``, slot ``i // LANES`` — the
     layout of ``core.tpu_format`` with a single chunk per page."""
-    S = syms.shape[0]
     codes_r = cb.codes[syms].T                        # (LANES, S)
     lens_r = cb.lengths[syms].T.astype(np.int64)      # (LANES, S)
-    starts = np.cumsum(lens_r, axis=1) - lens_r
-    lane_bits = starts[:, -1] + lens_r[:, -1]
-    stride = max(int(-(-int(lane_bits.max()) // 8)), MIN_STRIDE)
-
-    flat_lens = lens_r.reshape(-1)
-    within = _concat_aranges(flat_lens)
-    rep_rows = np.repeat(np.repeat(np.arange(LANES), S), flat_lens)
-    bitpos = np.repeat(starts.reshape(-1), flat_lens) + within
-    shift = np.repeat(flat_lens, flat_lens) - 1 - within
-    bitvals = (np.repeat(codes_r.reshape(-1), flat_lens) >> shift) & 1
-    bitmat = np.zeros((LANES, stride * 8), dtype=np.uint8)
-    bitmat[rep_rows, bitpos] = bitvals.astype(np.uint8)
-
-    weights = (1 << np.arange(7, -1, -1)).astype(np.uint16)
-    bytemat = (bitmat.reshape(LANES, stride, 8).astype(np.uint16)
-               * weights).sum(axis=2).astype(np.uint8)  # (LANES, stride)
+    ends = np.cumsum(lens_r, axis=1)
+    stride = max(int(-(-int(ends[:, -1].max()) // 8)), MIN_STRIDE)
+    bytemat = pack_codes(codes_r, lens_r, ends - lens_r, stride,
+                         cb.max_len)                  # (LANES, stride)
     return bytemat.T.copy()
 
 

@@ -8,7 +8,8 @@ kernel (``kernels/ecf8_decode.py``) generalized for cache pages:
     exponent field), so the peek is ``max_len`` bits and the window
     refills **up to two bytes** per round (vs one for 8-bit codes);
   * decode tables are **per page** (every page carries its own canonical
-    codebook) — each grid cell reads its own (1, L) table rows;
+    codebook) — all pages' (3, L) tables are scalar-prefetched into SMEM
+    and each grid cell reads its own rows as scalars;
   * the kernel emits *canonical symbol indices*; the (up to 256-entry)
     canonical permutation and the sign/mantissa fuse are applied by the
     caller as plain XLA gathers (``codec.assemble_pages_jnp``) — a
@@ -24,44 +25,45 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import interpret_mode
 from . import codec
 from .codec import LANES
 
 
-def _decode_page_kernel(limit_ref, first_ref, offset_ref, payload_ref,
-                        out_ref, *, sym_per_lane: int, stride: int,
-                        max_len: int):
-    S = sym_per_lane
-    payload = payload_ref[0].astype(jnp.uint32)        # (stride, LANES)
+def _decode_page_kernel(tab_ref, payload_ref, out_ref, *,
+                        sym_per_lane: int, stride: int, max_len: int):
+    S, L = sym_per_lane, max_len
+    # this page's (3, L) decode table rows, as SMEM scalars
+    base = pl.program_id(0) * (3 * L)
+    lims = [tab_ref[base + i] for i in range(L)]
+    firsts = [tab_ref[base + L + i] for i in range(L)]
+    offs = [tab_ref[base + 2 * L + i] for i in range(L)]
+    payload = payload_ref[0].astype(jnp.int32)         # (stride, LANES)
 
     win = ((payload[0:1, :] << 24) | (payload[1:2, :] << 16)
-           | (payload[2:3, :] << 8) | payload[3:4, :])  # (1, LANES)
+           | (payload[2:3, :] << 8) | payload[3:4, :]).astype(jnp.uint32)
     byteptr = jnp.full((1, LANES), 4, dtype=jnp.int32)
     bits_valid = jnp.full((1, LANES), 32, dtype=jnp.int32)
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (stride, LANES), 0)
 
     def round_fn(s, carry):
         win, byteptr, bits_valid = carry
-        peek = (win >> (32 - max_len)).astype(jnp.int32)  # (1, LANES)
+        peek = (win >> (32 - L)).astype(jnp.int32)      # (1, LANES)
 
         length = jnp.zeros((1, LANES), jnp.int32)
         sym_idx = jnp.zeros((1, LANES), jnp.int32)
         found = jnp.zeros((1, LANES), jnp.bool_)
-        for l in range(1, max_len + 1):                # unrolled, static
-            lim = limit_ref[0, l - 1]
-            fl = first_ref[0, l - 1]
-            off = offset_ref[0, l - 1]
-            cond = jnp.logical_and(peek < lim, jnp.logical_not(found))
-            idx_l = off + ((peek - fl) >> (max_len - l))
+        for l in range(1, L + 1):                      # unrolled, static
+            cond = jnp.logical_and(peek < lims[l - 1], jnp.logical_not(found))
+            idx_l = offs[l - 1] + ((peek - firsts[l - 1]) >> (L - l))
             length = jnp.where(cond, l, length)
             sym_idx = jnp.where(cond, idx_l, sym_idx)
             found = jnp.logical_or(found, cond)
 
-        pl.store(out_ref, (pl.dslice(0, 1), pl.dslice(s, 1), slice(None)),
-                 sym_idx.reshape(1, 1, LANES))
+        out_ref[0, pl.ds(s, 1), :] = sym_idx
 
         win = win << length.astype(jnp.uint32)
         bits_valid = bits_valid - length
@@ -69,8 +71,8 @@ def _decode_page_kernel(limit_ref, first_ref, offset_ref, payload_ref,
             need = bits_valid <= 24
             safe_ptr = jnp.minimum(byteptr, stride - 1)
             mask = row_iota == safe_ptr                # (stride, LANES)
-            nb = jnp.sum(jnp.where(mask, payload, jnp.uint32(0)), axis=0,
-                         keepdims=True)                # (1, LANES)
+            nb = jnp.sum(jnp.where(mask, payload, 0), axis=0,
+                         keepdims=True).astype(jnp.uint32)  # (1, LANES)
             shift = jnp.maximum(24 - bits_valid, 0).astype(jnp.uint32)
             win = jnp.where(need, win | (nb << shift), win)
             byteptr = byteptr + need.astype(jnp.int32)
@@ -80,44 +82,52 @@ def _decode_page_kernel(limit_ref, first_ref, offset_ref, payload_ref,
     jax.lax.fori_loop(0, S, round_fn, (win, byteptr, bits_valid))
 
 
+# pages per kernel call: 4096 pages x (3, 12) int32 tables = 576 KiB of SMEM
+_MAX_PAGES_PER_CALL = 4096
+
+
 @functools.partial(jax.jit, static_argnames=("n_elem", "interpret"))
 def decode_page_indices_pallas(payload, tables, *, n_elem: int,
-                               interpret: bool = True):
+                               interpret: bool | None = None):
     """Decode canonical symbol indices for N pages.
 
     Args:
       payload: (N, stride, LANES) uint8 zero-padded lane streams.
-      tables:  (N, 3, L) int32 — lj_limit / first_lj / offset per page.
+      tables:  (N, 3, L) int32 — lj_limit / first_lj / offset per page,
+        handed to the kernel through scalar prefetch (SMEM).
+      interpret: Pallas interpret mode; ``None`` picks it from the
+        backend (:func:`repro.kernels.interpret_mode`).
 
     Returns (N, S, LANES) int32 canonical indices.
     """
     N, stride, _ = payload.shape
+    if N > _MAX_PAGES_PER_CALL:   # the tables must fit in SMEM (1 MiB)
+        return jnp.concatenate([
+            decode_page_indices_pallas(
+                payload[i: i + _MAX_PAGES_PER_CALL],
+                tables[i: i + _MAX_PAGES_PER_CALL], n_elem=n_elem,
+                interpret=interpret)
+            for i in range(0, N, _MAX_PAGES_PER_CALL)])
     L = tables.shape[-1]
     S = codec.sym_per_lane(n_elem)
     kernel = functools.partial(_decode_page_kernel, sym_per_lane=S,
                                stride=stride, max_len=L)
     return pl.pallas_call(
         kernel,
-        grid=(N,),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda c: (c, 0)),    # lj_limit
-            pl.BlockSpec((1, L), lambda c: (c, 0)),    # first_lj
-            pl.BlockSpec((1, L), lambda c: (c, 0)),    # offset
-            pl.BlockSpec((1, stride, LANES), lambda c: (c, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, S, LANES), lambda c: (c, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N,),
+            in_specs=[pl.BlockSpec((1, stride, LANES),
+                                   lambda c, tab: (c, 0, 0))],
+            out_specs=pl.BlockSpec((1, S, LANES), lambda c, tab: (c, 0, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct((N, S, LANES), jnp.int32),
-        interpret=interpret,
-    )(
-        tables[:, 0].astype(jnp.int32),
-        tables[:, 1].astype(jnp.int32),
-        tables[:, 2].astype(jnp.int32),
-        payload,
-    )
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(tables.reshape(-1).astype(jnp.int32), payload)
 
 
 def decode_pages(payload, signmant, tables, perm, *, n_elem: int,
-                 dtype_name: str, interpret: bool = True):
+                 dtype_name: str, interpret: bool | None = None):
     """Full page decode via the Pallas kernel -> (N, n_elem) values.
 
     Same contract as ``codec.decode_pages_jnp`` (the pure-XLA oracle the
@@ -136,7 +146,7 @@ _SHARDED_DECODE_CACHE: dict = {}
 
 def decode_pages_sharded(payload, signmant, tables, perm, mesh, *,
                          n_elem: int, dtype_name: str,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """Decode a cold pool whose page dim shards over the mesh batch axes.
 
     The serving cache shards cold-pool leaves over the batch axes
@@ -161,12 +171,12 @@ def decode_pages_sharded(payload, signmant, tables, perm, mesh, *,
             return decode_pages(pay, sm, tab, prm, n_elem=n_elem,
                                 dtype_name=dtype_name, interpret=interpret)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(b_ax, None, None), P(b_ax, None),
                       P(b_ax, None, None), P(b_ax, None)),
             out_specs=P(b_ax, None),
-            check_rep=False,
+            check_vma=False,
         )
         _SHARDED_DECODE_CACHE[key] = fn
     return fn(payload, signmant, tables, perm)

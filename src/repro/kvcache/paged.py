@@ -61,6 +61,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from . import codec
 from .codec import LANES
+from repro.kernels import interpret_mode
 
 GARBAGE_PAGE = 0
 PAGED_KINDS = ("attn", "nope")   # "local" keeps its ring, recurrents a state
@@ -81,16 +82,19 @@ def page_write(pool, page_table, cur_len, kv):
     cur_len: (B,) write positions; kv: (B, n_kv, 1, hd).
 
     Tail pages are raw by construction (a page is only compressed once
-    full), so the scatter targets the raw pool; out-of-range ids are
-    dropped (``mode="drop"``) — which also makes this the per-shard write
-    under a mesh: the sharded caller translates global ids to local ones
-    and parks non-local entries out of range (``decode_sharded.
-    paged_decode_attention_sharded``)."""
+    full), so the scatter targets the raw pool; out-of-range and negative
+    ids are dropped (``mode="drop"``) — which also makes this the
+    per-shard write under a mesh: the sharded caller translates global
+    ids to local ones and parks non-local entries out of range
+    (``decode_sharded.paged_decode_attention_sharded``)."""
     ps = pool.shape[2]
     P = page_table.shape[1]
     p_idx = jnp.clip(cur_len // ps, 0, P - 1)
     off = cur_len % ps
     pids = jnp.take_along_axis(page_table, p_idx[:, None], axis=1)[:, 0]
+    # a swap sentinel (negative id, left in the row of a preempted slot)
+    # must drop too: a negative index would wrap onto a live page
+    pids = jnp.where(pids < 0, pool.shape[0], pids)
     return pool.at[pids, :, off, :].set(
         kv[:, :, 0, :].astype(pool.dtype), mode="drop")
 
@@ -175,8 +179,13 @@ def page_gather(pool, page_table, cpool=None):
     n_kv, ps, hd = pool.shape[1:]
     virtual = pool
     if cpool is not None:
+        from . import kernels
+        # the compiled Pallas page decode on a TPU, the jnp decoder (its
+        # bit-identical reference) elsewhere
+        decode = (codec.decode_pages_jnp if interpret_mode()
+                  else kernels.decode_pages)
         payload, signmant, tables, perm = cpool
-        dec = codec.decode_pages_jnp(
+        dec = decode(
             payload, signmant, tables, perm, n_elem=n_kv * ps * hd,
             dtype_name=str(pool.dtype))
         virtual = jnp.concatenate(
@@ -526,17 +535,28 @@ class PagedKVCache:
             return cache
         sh = self.shard_of_slot(slot)
         p = min(pos // self.page_size, self.pages_per_slot - 1)
-        while len(pages) <= p:
-            if not self._free[sh]:
-                cache = self._reclaim_prefix(cache, sh, 1)
-            if not self._free[sh]:
-                raise OutOfPages(
-                    f"shard {sh}: slot {slot} needs page {len(pages)}")
-            pid = self._alloc_raw(sh)
+        new = []
+        try:
+            while len(pages) + len(new) <= p:
+                if not self._free[sh]:
+                    cache = self._reclaim_prefix(cache, sh, 1)
+                if not self._free[sh]:
+                    raise OutOfPages(
+                        f"shard {sh}: slot {slot} needs page "
+                        f"{len(pages) + len(new)}")
+                new.append(self._alloc_raw(sh))
+        except OutOfPages:
+            # all or nothing: the caller keeps its cache (page table) on
+            # failure, so the slot's host page list must not grow either
+            for pid in reversed(new):
+                self._decref(pid)
+            raise
+        if new:
             cache = dict(cache)
             cache["page_table"] = cache["page_table"].at[
-                slot, len(pages)].set(pid)
-            pages.append(pid)
+                slot, len(pages):len(pages) + len(new)].set(
+                    jnp.asarray(new, jnp.int32))
+            pages.extend(new)
         return cache
 
     def rollback(self, cache: dict, slot: int, n_tokens: int):

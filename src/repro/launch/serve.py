@@ -1,11 +1,17 @@
 """Batched serving driver: the paper's RQ2 experiment shape.
 
-Loads (or synthesizes) weights, optionally compresses them to ECF8, and
+Synthesizes weights on the host from a seed (standing in for loading an FP8
+checkpoint), optionally compresses them to ECF8, puts them on the device and
 serves a batch of requests through the continuous-batching engine, printing
 the memory footprint of both weight representations and the achieved
-tokens/step.  On this CPU container the *throughput claim* is expressed as
-the roofline memory term (weight-streaming bytes) — see EXPERIMENTS §Perf —
-while this driver proves the end-to-end serving path runs and is bit-exact.
+tokens/step.  The pieces — :func:`synth_weights`, :func:`build_weights`,
+:func:`serve_requests`, :func:`release` — are what ``chip_smoke.py`` drives
+at full width on a TPU.
+
+On the CPU (tests, ``JAX_PLATFORMS=cpu``) the Pallas kernels run in
+interpret mode and ``--smoke`` configs keep the run small; on a TPU the same
+command runs the compiled kernels.  No timing printed here is a device
+measurement.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --smoke \
@@ -20,17 +26,18 @@ from __future__ import annotations
 
 import argparse
 import time
-from dataclasses import replace
+from concurrent.futures import ThreadPoolExecutor
+from dataclasses import dataclass, replace
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.configs import get, smoke_variant
 from repro.core import fp8
-from repro.core.store import compress_tree, fp8_cast_tree
+from repro.core.store import compress_tree, weight_leaf
 from repro.models import model as M
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.monitor import KVCacheMonitor
 from repro.runtime.trace_export import export_chrome_trace
 from repro.runtime.tracing import JaxProfilerHook
@@ -46,6 +53,104 @@ def tree_bytes(tree) -> int:
               if hasattr(x, "shape") else 0)
         for x in jax.tree_util.tree_leaves(
             tree, is_leaf=lambda t: hasattr(t, "nbytes_compressed")))
+
+
+# leaves with fewer elements per layer stay uncompressed (norms, biases)
+MIN_ELEMS = 4096
+# host synthesis block: one generator per block, so the weights depend on
+# the seed and the shapes only, not on the thread count
+_SYNTH_BLOCK = 1 << 22
+
+
+def _synth_block(out2d, r0, r1, std, seed):
+    g = np.random.default_rng(seed)
+    x = g.standard_normal((r1 - r0, out2d.shape[1]), dtype=np.float32)
+    x *= np.float32(std)
+    out2d[r0:r1] = x.astype(out2d.dtype)
+
+
+def synth_weights(cfg, seed: int = 0, min_elems: int = MIN_ELEMS) -> dict:
+    """Random weights for ``cfg`` on the host, from ``seed``: the stand-in
+    for loading an FP8 checkpoint.
+
+    The tree has ``models.model.init_params``' layout (shapes from
+    ``jax.eval_shape``; nothing is computed on a device).  Every weight
+    matrix (:func:`repro.core.store.weight_leaf`) is drawn normal with
+    1/sqrt(fan-in) scale (1/sqrt(d_model) for the embedding) in blocks and
+    cast to fp8 at once, so no float32 copy of a stacked leaf is ever
+    held; smaller matrices stay float32 and vectors (norm scales, biases)
+    are zeros.  Blocks are drawn on a thread pool."""
+    shapes = jax.eval_shape(
+        lambda: M.init_params(jax.random.PRNGKey(0), cfg))
+    flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    leaves, jobs = [], []
+    for i, (path, sds) in enumerate(flat):
+        is_weight, stacked = weight_leaf(path, sds.shape, min_elems)
+        if len(sds.shape) - stacked < 2:
+            leaves.append(np.zeros(sds.shape, np.float32))
+            continue
+        out = np.empty(sds.shape, fp8.FP8_DTYPE if is_weight
+                       else np.float32)
+        name = str(getattr(path[-1], "key", path[-1]))
+        std = (cfg.d_model if name == "embed" else sds.shape[-2]) ** -0.5
+        out2d = out.reshape(-1, sds.shape[-1])
+        rows = max(1, _SYNTH_BLOCK // sds.shape[-1])
+        jobs += [(out2d, r0, min(r0 + rows, out2d.shape[0]), std,
+                  (seed, i, r0)) for r0 in range(0, out2d.shape[0], rows)]
+        leaves.append(out)
+    with ThreadPoolExecutor(8) as ex:
+        list(ex.map(lambda j: _synth_block(*j), jobs))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+@dataclass
+class Weights:
+    """Serving weights built by :func:`build_weights` (host arrays)."""
+
+    host: dict      # host fp8 tree: the FP8 checkpoint stand-in/baseline
+    params: dict    # the serving tree (ECF8 containers, or ``host``)
+    report: dict    # compress_tree's report ({} when not compressed)
+    synth_s: float  # host synthesis seconds
+    encode_s: float  # host ECF8 encode seconds (0 when not compressed)
+
+
+def build_weights(cfg, *, seed: int = 0, fmt: str = "tpu") -> Weights:
+    """Synthesize on the host and encode (``fmt`` "tpu"/"fixedrate";
+    "none" serves the fp8 tree itself): the stand-in for loading a
+    checkpoint.  No device is touched; ``jax.device_put`` of ``params``
+    (the engine does it) moves the tree with one transfer per leaf, and
+    the float32 weights never reach a device."""
+    t0 = time.perf_counter()
+    host = synth_weights(cfg, seed)
+    t1 = time.perf_counter()
+    tree, report = host, {}
+    if fmt != "none":
+        tree, report = compress_tree(host, fmt=fmt, min_elems=MIN_ELEMS,
+                                     out_dtype=cfg.dtype)
+    t2 = time.perf_counter()
+    return Weights(host=host, params=tree, report=report, synth_s=t1 - t0,
+                   encode_s=t2 - t1)
+
+
+def serve_requests(eng: GenerationEngine, prompts, max_new: int,
+                   on_step=None) -> tuple[list, float]:
+    """Submit one greedy request per prompt and run the engine until all
+    finish; returns (finished requests in prompt order, wall seconds)."""
+    reqs = [Request(prompt=list(p), max_new_tokens=max_new)
+            for p in prompts]
+    for r in reqs:
+        eng.submit(r)
+    t0 = time.perf_counter()
+    eng.run(on_step=on_step)
+    return reqs, time.perf_counter() - t0
+
+
+def release(eng: GenerationEngine) -> None:
+    """Free the engine's device buffers (weights and cache) now, so the
+    next engine built in this process finds the memory free."""
+    for x in jax.tree_util.tree_leaves((eng.params, eng.cache)):
+        if isinstance(x, jax.Array) and not x.is_deleted():
+            x.delete()
 
 
 def main(argv=None):
@@ -189,24 +294,16 @@ def main(argv=None):
     except EngineConfigError as e:
         ap.error(str(e))
 
-    params = M.init_params(jax.random.PRNGKey(args.seed), cfg)
-    # FP8 baseline: the paper compresses released FP8 checkpoints
-    params_fp8 = fp8_cast_tree(params, min_elems=4096)
-
+    enable_compile_cache()
+    w = build_weights(cfg, seed=args.seed, fmt=args.compress)
     if args.compress != "none":
-        t0 = time.time()
-        params_c, report = compress_tree(
-            params, fmt=args.compress, min_elems=4096,
-            out_dtype=cfg.dtype if not args.smoke else "float32")
-        enc_s = time.time() - t0
-        fp8_b = max(report["fp8_bytes"], 1)
-        print(f"[serve] ECF8({args.compress}) encode {enc_s:.1f}s: "
-              f"{report['n_compressed']} tensors, fp8 {fp8_b / 1e6:.2f}MB ->"
-              f" {report['compressed_bytes'] / 1e6:.2f}MB "
-              f"({100 * (1 - report['compressed_bytes'] / fp8_b):.1f}% "
+        rep = w.report
+        fp8_b = max(rep["fp8_bytes"], 1)
+        print(f"[serve] ECF8({args.compress}) encode {w.encode_s:.1f}s: "
+              f"{rep['n_compressed']} tensors, fp8 {fp8_b / 1e6:.2f}MB ->"
+              f" {rep['compressed_bytes'] / 1e6:.2f}MB "
+              f"({100 * (1 - rep['compressed_bytes'] / fp8_b):.1f}% "
               f"saved)")
-    else:
-        params_c = params_fp8
 
     rng = np.random.default_rng(args.seed)
     system = rng.integers(1, cfg.vocab_size,
@@ -223,12 +320,9 @@ def main(argv=None):
               f"({sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(dparams)) / 1e6:.2f}M params), k={ecfg.spec_k}")
     tel = Telemetry(trace=args.trace_out is not None)
     mon = KVCacheMonitor(registry=tel.registry)
-    eng = GenerationEngine(params_c, cfg,
+    eng = GenerationEngine(w.params, cfg,
                            config=replace(ecfg, telemetry=tel,
                                           kv_monitor=mon))
-    reqs = [Request(prompt=p, max_new_tokens=args.max_new) for p in prompts]
-    for r in reqs:
-        eng.submit(r)
 
     profiler = None
     if args.jax_profile:
@@ -246,9 +340,7 @@ def main(argv=None):
             print(f"[serve] step {i + 1}: "
                   f"{serving_report_line(tel.registry)}")
 
-    t0 = time.time()
-    done = eng.run(on_step=on_step)
-    dt = time.time() - t0
+    done, dt = serve_requests(eng, prompts, args.max_new, on_step=on_step)
     if profiler is not None:
         profiler.close()
         print(f"[serve] jax.profiler trace in {args.jax_profile}")
@@ -317,12 +409,11 @@ def main(argv=None):
                   f"({s['n_resumed']} resumed)")
 
     if args.check_lossless and args.compress != "none":
-        eng2 = GenerationEngine(params_fp8, cfg, config=ecfg)
-        reqs2 = [Request(prompt=p, max_new_tokens=args.max_new)
-                 for p in prompts]
-        for r in reqs2:
-            eng2.submit(r)
-        done2 = eng2.run()
+        # the FP8 engine starts only once the ECF8 engine's buffers are
+        # free: at full width the two would not fit on one device together
+        release(eng)
+        eng = GenerationEngine(w.host, cfg, config=ecfg)
+        done2, _ = serve_requests(eng, prompts, args.max_new)
         same = all(a.out_tokens == b.out_tokens
                    for a, b in zip(done, done2))
         print(f"[serve] lossless check vs fp8 baseline: "

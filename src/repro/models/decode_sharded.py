@@ -40,7 +40,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kvcache import paged as paged_kv
 from .flash_attention import _gqa_scores, _gqa_combine
@@ -104,7 +103,7 @@ def decode_attention_update_sharded(q, k_cache, v_cache, new_k, new_v,
         o = _merge_stats(acc, m, l, "model")
         return o.astype(vc.dtype), kc, vc
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(b_ax, None, None, None),          # q
                   P(b_ax, None, "model", None),       # k cache
@@ -115,7 +114,7 @@ def decode_attention_update_sharded(q, k_cache, v_cache, new_k, new_v,
         out_specs=(P(b_ax, None, None, None),
                    P(b_ax, None, "model", None),
                    P(b_ax, None, "model", None)),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, new_k, new_v, vlen, slot)
 
 
@@ -279,7 +278,7 @@ def paged_prefill_chunk_sharded(q, new_k, new_v, cache, row, slot,
     cold_specs = tuple(P(b_ax, *(None,) * (x.ndim - 1))
                        for x in ((*cold_k, *cold_v) if has_cold else ()))
     rep = P(None, None, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, rep, rep,                 # q, new k, new v
                   pool_spec, pool_spec,          # k/v pool
@@ -288,7 +287,7 @@ def paged_prefill_chunk_sharded(q, new_k, new_v, cache, row, slot,
                   P(), P(),                      # slot, n_valid
                   *cold_specs),
         out_specs=(rep, pool_spec, pool_spec),
-        check_rep=False,
+        check_vma=False,
     )(q, new_k, new_v, k_pool, v_pool, row, positions, slot, n_valid,
       *((*cold_k, *cold_v) if has_cold else ()))
 
@@ -383,7 +382,7 @@ def paged_decode_attention_sharded(q, new_k, new_v, cache, page_table,
     pool_spec = P(b_ax, None, None, None)
     cold_specs = tuple(P(b_ax, *(None,) * (x.ndim - 1))
                        for x in ((*cold_k, *cold_v) if has_cold else ()))
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(b_ax, None, None, None),            # q
                   P(b_ax, None, None, None),            # new k
@@ -393,7 +392,7 @@ def paged_decode_attention_sharded(q, new_k, new_v, cache, page_table,
                   P(b_ax),                              # cur_len
                   *cold_specs),
         out_specs=(P(b_ax, None, None, None), pool_spec, pool_spec),
-        check_rep=False,
+        check_vma=False,
     )(q, new_k, new_v, k_pool, v_pool, page_table, cur_len,
       *((*cold_k, *cold_v) if has_cold else ()))
     return out

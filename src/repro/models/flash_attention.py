@@ -254,8 +254,7 @@ def flash_attention_sharded(q, k, v, mesh, *, causal: bool = True,
     the replicated k/v inputs.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-
+    
     ba = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     ba = ba if len(ba) != 1 else ba[0]
     B, Hq, Tq, _ = q.shape
@@ -275,10 +274,10 @@ def flash_attention_sharded(q, k, v, mesh, *, causal: bool = True,
         return flash_attention(q_l, k_l, v_l, causal, attn_softcap,
                                min(q_chunk, t_loc), kv_chunk, base)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(b_ax, None, t_ax, None), P(b_ax, None, None, None),
                   P(b_ax, None, None, None)),
         out_specs=P(b_ax, None, t_ax, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)

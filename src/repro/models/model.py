@@ -18,13 +18,14 @@ with ``jax.jit`` + ShapeDtypeStructs in the multi-pod dry-run.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, wraps
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core.store import weights_replicated_over
 from repro.kvcache import paged as paged_kv
 from . import recurrent as rec
 from .layers import (F32, apply_rope, blockwise_attention, decode_attention,
@@ -465,6 +466,25 @@ def encode_frames(params, cfg: ArchConfig, frames, dtype):
 # public entry points
 # --------------------------------------------------------------------------
 
+def _serving_entry(fn):
+    """A serving entry point traces its weight decodes for its ``mesh``
+    (the engine replicates the weights over it)."""
+    @wraps(fn)
+    def run(*args, mesh=None, **kw):
+        with weights_replicated_over(mesh):
+            return fn(*args, mesh=mesh, **kw)
+    return run
+
+
+def _decode_tied_embed(params, cfg: ArchConfig, dtype):
+    """``params`` with a tied embedding decoded (or cast) once, so the
+    token lookup and the unembedding of one call share it — one decode
+    when compressed, and the same graph for ECF8 and FP8 weights."""
+    if cfg.tie_embeddings:
+        return {**params, "embed": mat(params["embed"], dtype)}
+    return params
+
+
 def _embed(params, cfg: ArchConfig, tokens, dtype):
     x = jnp.take(mat(params["embed"], dtype), tokens, axis=0)
     if cfg.embed_scale:
@@ -561,6 +581,7 @@ def forward(params, cfg: ArchConfig, tokens, frames=None, mesh=None,
             remat: bool = False, constrain=None):
     """Training forward -> logits (B, T, V)."""
     dtype = jnp.dtype(cfg.dtype)
+    params = _decode_tied_embed(params, cfg, dtype)
     x = _embed(params, cfg, tokens, dtype)
     cross_ctx = (encode_frames(params, cfg, frames, dtype)
                  if cfg.encoder_decoder else None)
@@ -633,10 +654,12 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
     return {"units": units, "tail": tail, "cur_len": cur}
 
 
+@_serving_entry
 def prefill(params, cfg: ArchConfig, tokens, frames=None, mesh=None,
             max_len: int | None = None, constrain=None):
     """Process a prompt, build the cache -> (last-pos logits, cache)."""
     dtype = jnp.dtype(cfg.dtype)
+    params = _decode_tied_embed(params, cfg, dtype)
     B, T = tokens.shape
     max_len = max_len or T
     x = _embed(params, cfg, tokens, dtype)
@@ -700,6 +723,7 @@ def prefill(params, cfg: ArchConfig, tokens, frames=None, mesh=None,
                     "cur_len": jnp.full((), T, jnp.int32)}
 
 
+@_serving_entry
 def prefill_chunk(params, cfg: ArchConfig, tokens, cache, slot, n_valid,
                   mesh=None):
     """Process one fixed-size prompt chunk for ``slot`` of a paged cache.
@@ -717,6 +741,7 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, cache, slot, n_valid,
     new cache with ``cur_len[slot] += n_valid``).  Requires a paged cache
     and an architecture whose every layer is a paged kind ('attn'/'nope');
     the serving engine falls back to whole-prompt prefill otherwise."""
+    params = _decode_tied_embed(params, cfg, jnp.dtype(cfg.dtype))
     x, new_cache, n_valid = _chunk_stack(params, cfg, tokens, cache, slot,
                                          n_valid, mesh)
     last = jax.lax.dynamic_slice_in_dim(
@@ -725,6 +750,7 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, cache, slot, n_valid,
     return logits, new_cache
 
 
+@_serving_entry
 def verify_chunk(params, cfg: ArchConfig, tokens, cache, slot, n_valid,
                  mesh=None):
     """The speculative-decoding verify forward: :func:`prefill_chunk`'s
@@ -742,6 +768,7 @@ def verify_chunk(params, cfg: ArchConfig, tokens, cache, slot, n_valid,
     rejected suffix back afterwards (``PagedKVCache.rollback``) — the
     same timeline-rollback discipline as the chunked-prefill masked
     rows."""
+    params = _decode_tied_embed(params, cfg, jnp.dtype(cfg.dtype))
     x, new_cache, _ = _chunk_stack(params, cfg, tokens, cache, slot,
                                    n_valid, mesh)
     logits = _unembed(params, cfg, x, jnp.dtype(cfg.dtype))
@@ -806,9 +833,11 @@ def _make_cross_kv(params, cfg, cross_ctx, dtype):
     return jax.vmap(one_unit, in_axes=0)(params["units"])
 
 
+@_serving_entry
 def decode_step(params, cfg: ArchConfig, token, cache, mesh=None):
     """token: (B, 1) int32 -> (logits (B, 1, V), new cache)."""
     dtype = jnp.dtype(cfg.dtype)
+    params = _decode_tied_embed(params, cfg, dtype)
     cur_len = cache["cur_len"]
     x = _embed(params, cfg, token, dtype)
     x, new_cache, _ = _run_stack(params, cfg, x, dtype, mesh, "decode",

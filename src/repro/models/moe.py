@@ -20,7 +20,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .layers import mat, F32
 
@@ -132,13 +131,13 @@ def moe_apply(params, x, cfg, *, mesh=None, ep_axis: str = "model",
     if mesh is not None and ep_axis in mesh.axis_names and (
             mesh.shape[ep_axis] > 1):
         batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             local_moe, mesh=mesh,
             in_specs=(P(batch_axes, None, None), P(None, None),
                       P(ep_axis, None, None), P(ep_axis, None, None),
                       P(ep_axis, None, None)),
             out_specs=(P(batch_axes, None, None), P(batch_axes)),
-            check_rep=False,
+            check_vma=False,
         )(x.astype(dtype), gate_w, wi_gate, wi_up, wo)
         aux = aux.mean()
     else:

@@ -27,7 +27,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import fixedrate, fp8
 
@@ -75,11 +74,11 @@ def compressed_all_gather(mesh: Mesh, axis: str = "data"):
     def fn(w8_bits, table, esc_capacity: int):
         n = w8_bits.shape[0]
         body = partial(_gather_decode, axis=axis, esc_capacity=esc_capacity)
-        out, overflow = shard_map(
+        out, overflow = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis, *(None,) * (w8_bits.ndim - 1)), P(None)),
             out_specs=(P(None), P()),
-            check_rep=False,
+            check_vma=False,
         )(w8_bits, table)
         return out.reshape(n, *w8_bits.shape[1:]), overflow
 

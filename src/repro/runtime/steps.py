@@ -105,7 +105,7 @@ def compressed_param_specs(cfg: ArchConfig, bits_per_exp: float = 3.43,
         n_pad = C * LANES * S
         arrays = {
             "payload": sds(lead + (C, stride, LANES), jnp.uint8),
-            "signmant": sds(lead + (-(-per_layer // 2),), jnp.uint8),
+            "signmant": sds(lead + (C, -(-S // 2), LANES), jnp.uint8),
             "lj_limit": sds(lead + (8,), jnp.int32),
             "first_lj": sds(lead + (8,), jnp.int32),
             "offset": sds(lead + (8,), jnp.int32),

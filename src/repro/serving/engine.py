@@ -81,6 +81,7 @@ from dataclasses import dataclass, field
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.kvcache import OutOfPages, PagedKVCache, SwapStore
@@ -150,14 +151,11 @@ def _jitted_verify(cfg: ArchConfig, mesh, max_len: int, width: int):
 
 
 def compile_count(fn) -> int:
-    """Traced-program count of a jitted step (-1 when the runtime does
-    not expose it).  The perf-smoke tier and the recompile regression
-    test read this off ``engine._jitted_steps``/``_jitted_chunk`` entries
-    to pin "exactly one prefill compilation per chunk shape"."""
-    try:
-        return int(fn._cache_size())
-    except AttributeError:
-        return -1
+    """Traced-program count of a jitted step.  The perf-smoke tier and the
+    recompile regression test read this off ``engine._jitted_steps``/
+    ``_jitted_chunk`` entries to pin "exactly one prefill compilation per
+    chunk shape"."""
+    return int(fn._cache_size())
 
 
 def _splice(full, frag, slot: int, path_names):
@@ -226,7 +224,12 @@ class GenerationEngine:
                 "draft_params and draft_cfg must be provided together")
         config = config.validate(cfg)
         self.config = config
-        self.params, self.cfg = params, cfg
+        # place the weights once: replicated over a mesh (weights left on
+        # one device would be resharded by every step), otherwise on the
+        # default device (host arrays move in one transfer per leaf)
+        place = (None if config.mesh is None
+                 else NamedSharding(config.mesh, P()))
+        self.params, self.cfg = jax.device_put(params, place), cfg
         max_batch = self.max_batch = config.max_batch
         max_len = self.max_len = config.max_len
         mesh = self.mesh = config.mesh
@@ -269,7 +272,7 @@ class GenerationEngine:
         self.spec_on = config.draft_cfg is not None
         self.spec_k = config.spec_k
         if self.spec_on:
-            self.draft_params = config.draft_params
+            self.draft_params = jax.device_put(config.draft_params, place)
             self.draft_cfg = draft_cfg = config.draft_cfg
             self._draft_decode, self._draft_prefill = _jitted_steps(
                 draft_cfg, mesh, max_len)
@@ -295,6 +298,10 @@ class GenerationEngine:
         self._chunk = (_jitted_chunk(cfg, mesh, max_len, chunk)
                        if chunk else None)
         self.last_tok = jnp.zeros((max_batch, 1), jnp.int32)
+        # logits of the newest model call (prefill, chunk, verify or
+        # decode step): read-only observation, e.g. for bit-exactness
+        # probes against another weight format
+        self.last_logits = None
         self.steps = 0
         # telemetry is host-side observation only (None = off): per-request
         # lifecycle spans, engine-phase spans and the metrics registry
@@ -401,6 +408,7 @@ class GenerationEngine:
                                     args={"slot": slot})
         toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
         logits, frag = self._prefill(self.params, toks)
+        self.last_logits = logits
         if self.paged is not None:
             self.cache = self.paged.admit(self.cache, slot, frag,
                                           len(req.prompt))
@@ -798,6 +806,7 @@ class GenerationEngine:
             logits, new_cache = self._verify(self.params,
                                              jnp.asarray(toks), cache_in,
                                              s, k_eff + 1)
+            self.last_logits = logits
             self.cache = (restore_cold(new_cache, stash) if stash
                           else new_cache)
             p_log = np.asarray(logits[0], np.float32)[: k_eff + 1]
@@ -924,6 +933,7 @@ class GenerationEngine:
             tc0 = time.perf_counter()
             logits, new_cache = self._chunk(self.params, toks, cache_in,
                                             slot, n)
+            self.last_logits = logits
             self.cache = (restore_cold(new_cache, stash) if stash
                           else new_cache)
             self._prefill_pos[slot] = pos + n
@@ -1067,6 +1077,7 @@ class GenerationEngine:
         cache_in, stash = self._maybe_strip()
         logits, new_cache = self._decode(self.params, self.last_tok,
                                          cache_in)
+        self.last_logits = logits
         self.cache = (restore_cold(new_cache, stash) if stash
                       else new_cache)
         self.steps += 1

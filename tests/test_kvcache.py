@@ -100,6 +100,29 @@ def test_kernel_matches_jnp_and_oracle():
             np.asarray(codec.decode_page(c)).view(np.uint16), want)
 
 
+def test_kernel_splits_page_batches_beyond_smem(monkeypatch):
+    """Batches of more pages than one kernel call's SMEM table budget are
+    decoded in several calls, bit-identical to the jnp decode."""
+    monkeypatch.setattr(kernels, "_MAX_PAGES_PER_CALL", 2)
+    rng = np.random.default_rng(8)
+    cps = [codec.encode_page(np.asarray(jnp.asarray(
+        rng.standard_normal(1024) * s, jnp.bfloat16)))
+        for s in (0.1, 1.0, 10.0, 100.0, 1000.0)]
+    sb = max(c.stride for c in cps)
+    pay = np.zeros((len(cps), sb, codec.LANES), np.uint8)
+    for i, c in enumerate(cps):
+        pay[i, : c.stride] = c.payload
+    args = (jnp.asarray(pay), jnp.asarray(np.stack([c.signmant for c in cps])),
+            jnp.asarray(np.stack([c.tables() for c in cps])),
+            jnp.asarray(np.stack([c.perm for c in cps])))
+    kernels.decode_page_indices_pallas.clear_cache()
+    got = kernels.decode_pages(*args, n_elem=1024, dtype_name="bfloat16")
+    kernels.decode_page_indices_pallas.clear_cache()
+    want = codec.decode_pages_jnp(*args, n_elem=1024, dtype_name="bfloat16")
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint16),
+                                  np.asarray(want).view(np.uint16))
+
+
 # --------------------------------------------------------------------------
 # allocator
 # --------------------------------------------------------------------------
@@ -127,6 +150,73 @@ def test_allocator_lifecycle_and_garbage_page():
     cache = pkv.release(cache, 0)
     assert pkv.free_pages == 4 and not pkv._slot_pages
     assert np.all(np.asarray(cache["page_table"]) == 0)
+
+
+def test_ensure_is_all_or_nothing():
+    """A multi-page ``ensure`` that runs dry part-way raises OutOfPages
+    with the slot's page list, the free list and the page table exactly as
+    before (the caller keeps its old cache); a retry once pages are free
+    maps every new page into the page table."""
+    cfg = smoke_variant(get("qwen3-8b"))
+    pkv = PagedKVCache(cfg, 2, 32, dtype=jnp.float32, page_size=4,
+                       n_pages=6)
+    cache = pkv.admit_slot(pkv.init_cache(), 1, 1)
+    cache = pkv.admit_slot(cache, 0, 1)        # slot 0: 1 page, 3 free
+    pages, free = list(pkv._slot_pages[0]), [list(f) for f in pkv._free]
+    with pytest.raises(OutOfPages):
+        pkv.ensure(cache, 0, 19)               # needs 4 more pages
+    assert pkv._slot_pages[0] == pages and pkv._free == free
+    cache = pkv.release(cache, 1)              # frees one page
+    cache = pkv.ensure(cache, 0, 19)
+    row = np.asarray(cache["page_table"])[0]
+    assert len(pkv._slot_pages[0]) == 5
+    assert row[:5].tolist() == pkv._slot_pages[0]
+
+
+def test_page_write_drops_swap_sentinels():
+    """The decode-step K/V write of a slot whose row holds swap sentinels
+    (negative ids, left by a preempted request) is dropped, never wrapped
+    onto a live page."""
+    from repro.kvcache.paged import page_write
+    pool = jnp.zeros((6, 2, 4, 8), jnp.float32)
+    table = jnp.asarray([[3, 4], [-1, -2]], jnp.int32)
+    cur_len = jnp.asarray([1, 2], jnp.int32)
+    out = page_write(pool, table, cur_len, jnp.ones((2, 2, 1, 8)))
+    touched = np.flatnonzero(np.asarray(out).reshape(6, -1).any(axis=1))
+    assert touched.tolist() == [3]
+
+
+def test_fault_path_runs_page_kernel_in_backend_mode(monkeypatch):
+    """A swap fault restores pages through ``kernels.decode_pages`` with no
+    ``interpret`` override: the kernel's mode comes from the backend
+    helper (compiled on a TPU, interpreted here)."""
+    calls, modes = [], []
+    real_decode, real_mode = kernels.decode_pages, kernels.interpret_mode
+
+    def spy_decode(*args, **kw):
+        calls.append(kw.get("interpret"))
+        return real_decode(*args, **kw)
+
+    def spy_mode():
+        modes.append(real_mode())
+        return modes[-1]
+
+    monkeypatch.setattr(kernels, "decode_pages", spy_decode)
+    monkeypatch.setattr(kernels, "interpret_mode", spy_mode)
+    kernels.decode_page_indices_pallas.clear_cache()   # re-resolve the mode
+    cfg = smoke_variant(get("qwen3-8b"))
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    eng = GenerationEngine(params, cfg, config=EngineConfig(
+        max_batch=2, max_len=48, page_size=8, n_pages=5,
+        swap_bytes=1 << 28))
+    for i in range(6):
+        eng.submit(Request(prompt=[i + 1] * (7 + 3 * (i % 3)),
+                           max_new_tokens=10, id=14_000 + i))
+    eng.run()
+    assert eng.paged.swap.swap_in_bytes > 0
+    assert calls and all(c is None for c in calls)
+    assert modes and all(m == (jax.default_backend() != "tpu")
+                         for m in modes)
 
 
 # --------------------------------------------------------------------------

@@ -447,6 +447,30 @@ def test_chunked_midprefill_preempt_resume_bit_identical():
     assert eng.scheduler.n_resumed >= 1
 
 
+def test_multi_page_chunk_preempt_bit_identical():
+    """Regression: a prefill chunk spanning several pages runs out of
+    pages part-way through its ``ensure``, preempts a victim and retries.
+    ``ensure`` is all-or-nothing, so the retry maps every page of the
+    chunk (a partial grant once stayed on the host page list without
+    reaching the page table, and the chunk's K/V landed in the garbage
+    page).  Tokens stay bit-identical to the monolithic engine."""
+    cfg = smoke_variant(get("qwen3-8b"))
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    prompts = [[(7 * i + j) % 500 + 1 for j in range(n)]
+               for i, n in enumerate([40, 30, 36, 20])]
+
+    def stream():
+        return [Request(prompt=list(p), max_new_tokens=6, id=13_000 + i)
+                for i, p in enumerate(prompts)]
+
+    mono, _ = _serve(params, cfg, stream(), cache_mode="monolithic")
+    over, eng = _serve(params, cfg, stream(), cache_mode="paged",
+                       page_size=4, n_pages=14, swap_bytes=-1,
+                       prefill_chunk=16)
+    assert eng.scheduler.n_preempted > 0
+    assert over == mono
+
+
 def test_scheduler_token_budget_blocks_new_prefill_work():
     """pick() with an exhausted prefill budget admits only zero-prefill
     items (decode-phase resumes); a budget-blocked class head blocks its
