@@ -132,11 +132,15 @@ def decode_pages(payload, signmant, tables, perm, *, n_elem: int,
 
     Same contract as ``codec.decode_pages_jnp`` (the pure-XLA oracle the
     serving engine uses in-graph); this path routes the entropy decode
-    through the TPU kernel and fuses perm + sign/mantissa outside."""
-    sym_idx = decode_page_indices_pallas(payload, tables, n_elem=n_elem,
-                                         interpret=interpret)
-    return codec.finish_pages_jnp(sym_idx, signmant, perm, n_elem=n_elem,
-                                  dtype_name=dtype_name)
+    through the TPU kernel and fuses perm + sign/mantissa outside.  The
+    two parts carry the scopes ``kv.cold_decode`` and ``kv.cold_permute``
+    in their ops' metadata, so a profiler trace names them."""
+    with jax.named_scope("kv.cold_decode"):
+        sym_idx = decode_page_indices_pallas(payload, tables, n_elem=n_elem,
+                                             interpret=interpret)
+    with jax.named_scope("kv.cold_permute"):
+        return codec.finish_pages_jnp(sym_idx, signmant, perm,
+                                      n_elem=n_elem, dtype_name=dtype_name)
 
 
 # (mesh, batch axes, n_elem, dtype, interpret) -> shard_map'ed decode;

@@ -51,7 +51,6 @@ lifecycle (admit -> ensure -> compress cold -> release).
 """
 from __future__ import annotations
 
-import time
 import warnings
 
 import numpy as np
@@ -62,6 +61,7 @@ from repro.configs.base import ArchConfig
 from . import codec
 from .codec import LANES
 from repro.kernels import interpret_mode
+from repro.runtime.tracing import span
 
 GARBAGE_PAGE = 0
 PAGED_KINDS = ("attn", "nope")   # "local" keeps its ring, recurrents a state
@@ -340,8 +340,13 @@ class PagedKVCache:
         self.n_cow_splits = 0           # shared pages split before a write
         self.swap = None                # SwapStore (attach_swap)
         self.telemetry = None           # serving.telemetry.Telemetry
-        #   (engine-set; evict/fault publish page counts and host<->device
-        #   swap spans through it — pure observation)
+        #   (engine-set; evict/fault publish page counts through it, and
+        #   the spans also feed its tracer — pure observation)
+
+    @property
+    def tracer(self):
+        """The span buffer the cache's spans also feed (or None)."""
+        return None if self.telemetry is None else self.telemetry.tracer
 
     # -- structure ---------------------------------------------------------
 
@@ -710,37 +715,36 @@ class PagedKVCache:
         # cold-first: already-compressed pages are the cheapest victims
         idxs.sort(key=lambda p: (pages[p] < self.n_pages, p))
         cache = dict(cache)
-        t0 = time.perf_counter()
         n_moved = 0
-        for p in idxs:
-            e = pages[p]
-            if e < 0 or e == GARBAGE_PAGE:
-                continue
-            if e >= self.n_pages:
-                cs = e - self.n_pages
-                sp = self._copy_cold_page(cache, cs)
-                key = self.swap.put(sp, sh)
-                self._cold_free[cs // max(self.cold_per_shard, 1)].append(cs)
-                self._cold_bytes.pop(cs, None)
-            else:
-                # a shared page gets a *private* swap copy and a decref:
-                # the prefix index keeps its own (still-resident) reference,
-                # so sharing degrades gracefully under memory pressure and
-                # detach_slot's all-swapped assertion holds
-                sp = self._encode_raw_page(cache, e)
-                key = self.swap.put(sp, sh)
-                self._decref(e)
-            pages[p] = -(key + 1)
-            cache["page_table"] = cache["page_table"].at[slot, p].set(
-                -(key + 1))
-            n_moved += 1
+        with span("swap.evict", self.tracer, slot=slot) as ev:
+            for p in idxs:
+                e = pages[p]
+                if e < 0 or e == GARBAGE_PAGE:
+                    continue
+                if e >= self.n_pages:
+                    cs = e - self.n_pages
+                    sp = self._copy_cold_page(cache, cs)
+                    key = self.swap.put(sp, sh)
+                    self._cold_free[cs // max(self.cold_per_shard, 1)
+                                    ].append(cs)
+                    self._cold_bytes.pop(cs, None)
+                else:
+                    # a shared page gets a *private* swap copy and a
+                    # decref: the prefix index keeps its own (still-
+                    # resident) reference, so sharing degrades gracefully
+                    # under memory pressure and detach_slot's all-swapped
+                    # assertion holds
+                    sp = self._encode_raw_page(cache, e)
+                    key = self.swap.put(sp, sh)
+                    self._decref(e)
+                pages[p] = -(key + 1)
+                cache["page_table"] = cache["page_table"].at[slot, p].set(
+                    -(key + 1))
+                n_moved += 1
+            ev.done(pages=n_moved)
         if n_moved and self.telemetry is not None:
             self.telemetry.registry.counter(
                 "kvcache_evict_pages_total").inc(n_moved)
-            if self.telemetry.tracer is not None:
-                self.telemetry.tracer.complete(
-                    "swap", "evict", "engine", t0,
-                    args={"slot": slot, "pages": n_moved})
         return cache
 
     def fault(self, cache: dict, slot: int, page_idxs=None):
@@ -760,6 +764,14 @@ class PagedKVCache:
                             else page_idxs) if pages[p] < 0]
         if not idxs:
             return cache
+        with span("swap.fault", self.tracer, slot=slot, pages=len(idxs)):
+            cache = self._fault(cache, slot, pages, idxs)
+        if self.telemetry is not None:
+            self.telemetry.registry.counter(
+                "kvcache_fault_pages_total").inc(len(idxs))
+        return cache
+
+    def _fault(self, cache: dict, slot: int, pages: list, idxs: list):
         sh = self.shard_of_slot(slot)
         # placement plan (peek only): cold-swapped pages take cold slots
         # while they last, everything else needs a raw page
@@ -782,7 +794,6 @@ class PagedKVCache:
                 f"{len(self._free[sh])} free")
 
         cache = dict(cache)
-        t0 = time.perf_counter()
         raw_jobs = []                   # (entry, pid) scattered after decode
         for p, sp, to_cold in plan:
             self.swap.pop(-pages[p] - 1)
@@ -814,13 +825,6 @@ class PagedKVCache:
 
         if raw_jobs:
             cache = self._restore_raw(cache, raw_jobs)
-        if self.telemetry is not None:
-            self.telemetry.registry.counter(
-                "kvcache_fault_pages_total").inc(len(plan))
-            if self.telemetry.tracer is not None:
-                self.telemetry.tracer.complete(
-                    "swap", "fault", "engine", t0,
-                    args={"slot": slot, "pages": len(plan)})
         return cache
 
     def _restore_raw(self, cache: dict, jobs):
@@ -975,7 +979,6 @@ class PagedKVCache:
         shared: list[int] = []
         raw_jobs = []               # (SwapEntry, pid) for _restore_raw
         n_faulted = 0
-        t0 = time.perf_counter()
         if self.prefix is not None:
             for i in range((len(prompt) - 1) // ps if len(prompt) else 0):
                 key = self._prefix_key(prompt, i)
@@ -1000,7 +1003,9 @@ class PagedKVCache:
                 self.prefix.touch(key)
                 shared.append(ent)
         if raw_jobs:
-            cache = self._restore_raw(cache, raw_jobs)
+            with span("swap.prefix_fault", self.tracer, slot=slot,
+                      pages=n_faulted):
+                cache = self._restore_raw(cache, raw_jobs)
 
         extra = max(min(extra, self.pages_per_slot - len(shared)), 0)
         free = self._free[sh]
@@ -1023,10 +1028,6 @@ class PagedKVCache:
         if n_faulted and self.telemetry is not None:
             self.telemetry.registry.counter(
                 "kvcache_fault_pages_total").inc(n_faulted)
-            if self.telemetry.tracer is not None:
-                self.telemetry.tracer.complete(
-                    "swap", "prefix_fault", "engine", t0,
-                    args={"slot": slot, "pages": n_faulted})
         return cache, len(shared) * ps
 
     def register_prefix(self, slot: int, prompt, n_tokens: int) -> None:
@@ -1150,11 +1151,24 @@ class PagedKVCache:
 
     # -- cold compression --------------------------------------------------
 
-    def compress_cold_pages(self, cache: dict, slot: int, pos: int):
+    def compress_cold(self, cache: dict, slots):
+        """:func:`compress_cold_pages` for each ``(slot, pos)`` of
+        ``slots``, under one ``kv.code_pages`` span whose end marker
+        counts the pages coded, the pages left raw and the coded bytes."""
+        tally = [0, 0, 0]
+        with span("kv.code_pages", self.tracer, slots=len(slots)) as sp:
+            for slot, pos in slots:
+                cache = self.compress_cold_pages(cache, slot, pos, tally)
+            sp.done(pages=tally[0], raw=tally[1], bytes=tally[2])
+        return cache
+
+    def compress_cold_pages(self, cache: dict, slot: int, pos: int,
+                            tally: list | None = None):
         """Entropy-code the slot's full (non-tail) pages into the cold pool.
 
         ``pos`` is the next write position; pages strictly below
-        ``pos // page_size`` are complete and never written again."""
+        ``pos // page_size`` are complete and never written again.
+        ``tally``, if given, adds [pages coded, pages left raw, bytes]."""
         if not self.compress or slot not in self._slot_pages:
             return cache
         sh = self.shard_of_slot(slot)
@@ -1169,51 +1183,71 @@ class PagedKVCache:
                 continue
             if not self._cold_free[sh]:
                 return cache
-            cache, ok = self._compress_one(cache, slot, p)
-            if not ok:
-                self._skip[slot].add(p)
+            with span("kv.code_page", self.tracer, slot=slot,
+                      page=p) as sp:
+                cache, nbytes = self._compress_one(cache, slot, p)
+                if nbytes is None:
+                    self._skip[slot].add(p)
+                    sp.done(raw=1)
+            if tally is not None:
+                tally[0] += nbytes is not None
+                tally[1] += nbytes is None
+                tally[2] += nbytes or 0
         return cache
 
     def _compress_one(self, cache: dict, slot: int, p: int):
+        """(cache, coded bytes) with page ``p`` of ``slot`` moved into a
+        cold slot, or (cache, None) when it does not fit the stride
+        budget and stays raw."""
         pid = self._slot_pages[slot][p]
-        enc = []                    # (section, name, stacked, kn, u, page)
-        for section, name, kind, stacked in self._groups():
-            if kind not in PAGED_KINDS:
-                continue
-            leafd = cache[section][name]
-            for kn in ("k", "v"):
-                pool = leafd[f"{kn}_pool"]
-                units = range(self.n_units) if stacked else (None,)
-                for u in units:
-                    # slice the one page on device; only page-sized data
-                    # crosses to the host for encoding
-                    page = np.asarray(pool[u, pid] if stacked else pool[pid])
-                    cp = codec.encode_page(page)
-                    if cp.stride > self.stride_budget:
-                        return cache, False     # incompressible: stay raw
-                    enc.append((section, name, stacked, kn, u, cp))
+        subs = []                   # (section, name, stacked, kn, u, page)
+        tr = self.tracer
+        with span("kv.page_fetch", tr):
+            for section, name, kind, stacked in self._groups():
+                if kind not in PAGED_KINDS:
+                    continue
+                leafd = cache[section][name]
+                for kn in ("k", "v"):
+                    pool = leafd[f"{kn}_pool"]
+                    for u in (range(self.n_units) if stacked else (None,)):
+                        # slice the one page on device; only page-sized
+                        # data crosses to the host for encoding
+                        subs.append((section, name, stacked, kn, u,
+                                     np.asarray(pool[u, pid] if stacked
+                                                else pool[pid])))
+        enc = []
+        with span("kv.page_encode", tr):
+            for *where, page in subs:
+                cp = codec.encode_page(page)
+                if cp.stride > self.stride_budget:
+                    return cache, None          # incompressible: stay raw
+                enc.append((*where, cp))
 
         cslot = self._cold_free[self.shard_of_slot(slot)].pop()
         total = 0
         cache = dict(cache)
-        for section, name, stacked, kn, u, cp in enc:
-            pay = np.zeros((self.stride_budget, LANES), np.uint8)
-            pay[: cp.stride] = cp.payload
-            leafd = dict(cache[section][name])
-            idx = (u, cslot) if stacked else (cslot,)
-            leafd[f"{kn}_cpl"] = leafd[f"{kn}_cpl"].at[idx].set(pay)
-            leafd[f"{kn}_csm"] = leafd[f"{kn}_csm"].at[idx].set(cp.signmant)
-            leafd[f"{kn}_ctab"] = leafd[f"{kn}_ctab"].at[idx].set(cp.tables())
-            leafd[f"{kn}_cperm"] = leafd[f"{kn}_cperm"].at[idx].set(cp.perm)
-            cache[section] = {**cache[section], name: leafd}
-            total += cp.nbytes()
+        with span("kv.page_install", tr):
+            for section, name, stacked, kn, u, cp in enc:
+                pay = np.zeros((self.stride_budget, LANES), np.uint8)
+                pay[: cp.stride] = cp.payload
+                leafd = dict(cache[section][name])
+                idx = (u, cslot) if stacked else (cslot,)
+                leafd[f"{kn}_cpl"] = leafd[f"{kn}_cpl"].at[idx].set(pay)
+                leafd[f"{kn}_csm"] = leafd[f"{kn}_csm"].at[idx].set(
+                    cp.signmant)
+                leafd[f"{kn}_ctab"] = leafd[f"{kn}_ctab"].at[idx].set(
+                    cp.tables())
+                leafd[f"{kn}_cperm"] = leafd[f"{kn}_cperm"].at[idx].set(
+                    cp.perm)
+                cache[section] = {**cache[section], name: leafd}
+                total += cp.nbytes()
 
         entry = self.n_pages + cslot
         self._slot_pages[slot][p] = entry
         cache["page_table"] = cache["page_table"].at[slot, p].set(entry)
         self._decref(pid)
         self._cold_bytes[cslot] = total
-        return cache, True
+        return cache, total
 
     # -- accounting --------------------------------------------------------
 

@@ -39,8 +39,10 @@ def get_attention_impl() -> str:
 
 
 def mat(w, dtype):
-    """Materialize (decode if compressed) and cast a weight for use."""
-    return materialize(w, dtype=dtype)
+    """Materialize (decode if compressed) and cast a weight for use; the
+    ops carry the scope ``weights.decode`` in their metadata."""
+    with jax.named_scope("weights.decode"):
+        return materialize(w, dtype=dtype)
 
 
 # --------------------------------------------------------------------------

@@ -15,10 +15,9 @@ worker every step.  The mitigation stack implemented/documented here:
      ``checkpoint.manager``).
 
 This is host-side instrumentation (wall clock), so it works identically on
-CPU and real pods.  The serving engine reuses :class:`StragglerMonitor`
-for decode-step outlier detection, surfacing alarms through the
-telemetry registry (``serving_decode_straggler_total``; see
-``docs/OBSERVABILITY.md``).
+CPU and real pods.  The trainer (``launch/train.py``) uses
+:class:`StragglerMonitor`; the serving engine times every step with its
+spans instead (``runtime.tracing.span``).
 """
 from __future__ import annotations
 

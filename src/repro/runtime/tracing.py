@@ -1,37 +1,42 @@
-"""Per-request span tracing for the serving engine (host wall-clock).
+"""Host spans of the serving engine, on the profiler's clock.
 
 The tracing half of the telemetry subsystem (metrics live in
 ``serving.telemetry``; the Chrome-trace/Perfetto JSON exporter in
-``runtime.trace_export``).  The engine records every lifecycle
-transition it already performs — submit -> admitted -> prefilling (per
-chunk) -> decoding -> preempted/resumed -> finished — as **state spans**
-on a per-request track, plus **engine-phase spans** (prefill phase,
-decode step, evict/fault a.k.a. host<->device swap, preempt/resume) on
-the engine track, and per-step counter samples (queue depth, pages in
-use) that render as counter tracks in Perfetto.
+``runtime.trace_export``).
 
-Design constraints (the tracer runs inside the serving step loop):
+:class:`span` is the one span primitive.  It always enters a
+``jax.profiler.TraceAnnotation`` named ``<cat>.<name>`` (``engine.step``,
+``kv.code_pages``, ``swap.fault``), so a ``jax.profiler`` trace holds the
+engine's phases on the same clock as the device ops and an idle gap of
+the device reads as the phase the host was in.  With no profiler session
+running an annotation costs about a microsecond, so the spans are always
+on; they are kept at step, phase and page granularity, never per token
+or per slot.  Arguments given on entry read back as the event's stats
+(``jax.profiler.ProfileData``); counts known only at the end go on a
+zero-length ``<cat>.<name>.done`` child (:meth:`span.done`).
 
-  * **bounded**: the event buffer is capped at ``capacity``; overflow
-    bumps ``n_dropped`` instead of growing — a runaway run degrades to
-    a truncated trace, never to unbounded host memory;
-  * **cheap**: an event is one small tuple append; timestamps are raw
-    ``perf_counter`` floats (exported to microseconds only at dump
-    time); nothing is formatted or serialized until export.  There is
-    no per-token work at all — events are per step / per transition.
+When a :class:`SpanTracer` is attached (``Telemetry(trace=True)``), each
+span is also appended to its buffer as ``(cat, name)``, which feeds the
+Chrome-trace export (``launch/serve.py --trace-out``), together with the
+per-request **state spans** of :class:`RequestStateTracker` (queued ->
+prefilling -> decoding -> preempted -> finished) and per-step counter
+samples (queue depth, pages in use).
 
-Event tuples are ``(ph, cat, name, track, ts, dur, args)`` with ``ph``
-one of ``"X"`` (complete span), ``"I"`` (instant), ``"C"`` (counter
-sample; ``args`` is the numeric value).  ``track`` is a string:
-``"engine"`` (engine-phase rows) or ``"req:<id>"`` (one row per
-request).  ``runtime.trace_export`` maps tracks to Chrome-trace
+The buffer is **bounded**: capped at ``capacity``; overflow bumps
+``n_dropped`` instead of growing.  An event is one small tuple append,
+``(ph, cat, name, track, ts, dur, args)`` with ``ph`` one of ``"X"``
+(complete span), ``"I"`` (instant), ``"C"`` (counter sample; ``args`` is
+the numeric value); timestamps are raw ``perf_counter`` floats.
+``track`` is ``"engine"`` (engine-phase rows) or ``"req:<id>"`` (one row
+per request); ``runtime.trace_export`` maps tracks to Chrome-trace
 pid/tid pairs.
 """
 from __future__ import annotations
 
 import time
 import warnings
-from contextlib import contextmanager
+
+import jax
 
 ENGINE_TRACK = "engine"
 
@@ -80,14 +85,53 @@ class SpanTracer:
         self._push(("C", "metric", name, track, self._clock(), 0.0,
                     float(value)))
 
-    @contextmanager
     def span(self, cat: str, name: str, track: str = ENGINE_TRACK,
-             args: dict | None = None):
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            self.complete(cat, name, track, t0, args=args)
+             args: dict | None = None) -> "span":
+        return span(f"{cat}.{name}", self, track, **(args or {}))
+
+
+class span:
+    """``with span("engine.decode_step", tracer, rows=16): ...`` — one
+    host span (module docstring).  ``tracer`` may be None (profiler
+    only).  The span closes when its body raises too, so aborted work
+    (``swap.fault`` on ``OutOfPages``) still shows.  ``seconds`` holds
+    the span's length once it has closed."""
+
+    __slots__ = ("name", "tracer", "track", "args", "t0", "seconds",
+                 "_ann")
+
+    def __init__(self, name: str, tracer: SpanTracer | None = None,
+                 track: str = ENGINE_TRACK, **args):
+        self.name, self.tracer, self.track, self.args = (name, tracer,
+                                                         track, args)
+
+    def _now(self) -> float:
+        return (time.perf_counter() if self.tracer is None
+                else self.tracer.now())
+
+    def __enter__(self) -> "span":
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        self.t0 = self._now()
+        return self
+
+    def done(self, **args) -> None:
+        """Counts known only at the end: a zero-length
+        ``<name>.done`` child in the profiler, the span's own args in
+        the buffer."""
+        with jax.profiler.TraceAnnotation(self.name + ".done", **args):
+            pass
+        self.args.update(args)
+
+    def __exit__(self, *exc) -> bool:
+        t1 = self._now()
+        self.seconds = t1 - self.t0
+        if self.tracer is not None:
+            cat, _, name = self.name.partition(".")
+            self.tracer.complete(cat, name, self.track, self.t0, t1,
+                                 self.args or None)
+        self._ann.__exit__(*exc)
+        return False
 
 
 class RequestStateTracker:
@@ -134,11 +178,11 @@ class RequestStateTracker:
 class JaxProfilerHook:
     """Opt-in ``jax.profiler`` capture over an engine-step range.
 
-    Drives ``jax.profiler.start_trace``/``stop_trace`` so a device-side
-    profile (XLA execution, transfers) lands next to the host-side span
-    trace for the same steps (``launch/serve.py --jax-profile DIR
-    --profile-steps A:B``).  Failures to start/stop are downgraded to
-    warnings — profiling must never take down a serving run."""
+    Drives ``jax.profiler.start_trace``/``stop_trace`` over those steps
+    (``launch/serve.py --jax-profile DIR --profile-steps A:B``): the
+    profile holds the device's ops and the engine's spans on one clock.
+    Failures to start/stop are downgraded to warnings — profiling must
+    never take down a serving run."""
 
     def __init__(self, logdir: str, start_step: int = 0,
                  stop_step: int | None = None):
@@ -153,7 +197,6 @@ class JaxProfilerHook:
     def on_step(self, step: int) -> None:
         if not self.done and not self.active and step >= self.start_step:
             try:
-                import jax
                 jax.profiler.start_trace(self.logdir)
                 self.active = True
             except Exception as e:                  # pragma: no cover
@@ -165,7 +208,6 @@ class JaxProfilerHook:
     def close(self) -> None:
         if self.active:
             try:
-                import jax
                 jax.profiler.stop_trace()
             except Exception as e:                  # pragma: no cover
                 warnings.warn(f"jax.profiler stop failed: {e}")

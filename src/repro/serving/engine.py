@@ -89,6 +89,7 @@ from repro.kvcache.paged import restore_cold, strip_cold
 from repro.kvcache.swap import SwapExhausted
 from repro.models import model as M
 from repro.runtime import sharding as SH
+from repro.runtime.tracing import span
 from . import spec as SPEC
 from .config import EngineConfig
 from .sampler import greedy, request_key, root_key, sample_logits
@@ -306,12 +307,11 @@ class GenerationEngine:
         # telemetry is host-side observation only (None = off): per-request
         # lifecycle spans, engine-phase spans and the metrics registry
         telemetry = self.tel = config.telemetry
+        # the buffer the engine's spans also feed (runtime.tracing.span)
+        self.tracer = telemetry.tracer if telemetry is not None else None
         self._submit_t: dict = {}       # request id -> submit wall time
-        self._straggler = None
         if telemetry is not None:
-            from repro.runtime.monitor import (KVCacheMonitor,
-                                               StragglerMonitor)
-            self._straggler = StragglerMonitor()
+            from repro.runtime.monitor import KVCacheMonitor
             if self.kv_monitor is None:
                 self.kv_monitor = KVCacheMonitor(
                     registry=telemetry.registry)
@@ -402,43 +402,40 @@ class GenerationEngine:
 
     def _start(self, slot: int, req: Request):
         """Prefill a fresh request and splice it into ``slot``."""
-        tel, t0 = self.tel, time.perf_counter()
+        tel = self.tel
         if tel is not None and tel.requests is not None:
             tel.requests.transition(req.id, "prefilling",
                                     args={"slot": slot})
-        toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        logits, frag = self._prefill(self.params, toks)
-        self.last_logits = logits
-        if self.paged is not None:
-            self.cache = self.paged.admit(self.cache, slot, frag,
-                                          len(req.prompt))
-        else:
-            self.cache = splice_fragment(self.cache, frag, slot)
-        if self.spec_on:
-            # spec-aware prefill: the paired draft consumes the prompt
-            # too (its logits are unused — the first token is sampled
-            # from the *target* prefill, identical to target-only)
-            _, dfrag = self._draft_prefill(self.draft_params, toks)
-            self.draft_cache = splice_fragment(self.draft_cache, dfrag,
-                                               slot)
-        self._host_len[slot] = len(req.prompt)
-        tok = self._sample_one(logits, req)
-        req.out_tokens.append(int(tok))
-        self.last_tok = self.last_tok.at[slot, 0].set(tok)
-        self.slots[slot] = req
+        with span("engine.prefill", self.tracer, req=req.id,
+                  tokens=len(req.prompt)) as sp:
+            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            logits, frag = self._prefill(self.params, toks)
+            self.last_logits = logits
+            if self.paged is not None:
+                self.cache = self.paged.admit(self.cache, slot, frag,
+                                              len(req.prompt))
+            else:
+                self.cache = splice_fragment(self.cache, frag, slot)
+            if self.spec_on:
+                # spec-aware prefill: the paired draft consumes the prompt
+                # too (its logits are unused — the first token is sampled
+                # from the *target* prefill, identical to target-only)
+                _, dfrag = self._draft_prefill(self.draft_params, toks)
+                self.draft_cache = splice_fragment(self.draft_cache, dfrag,
+                                                   slot)
+            self._host_len[slot] = len(req.prompt)
+            tok = self._sample_one(logits, req)
+            req.out_tokens.append(int(tok))
+            self.last_tok = self.last_tok.at[slot, 0].set(tok)
+            self.slots[slot] = req
         if tel is not None:
-            now = time.perf_counter()
             sub = self._submit_t.get(req.id)
             if sub is not None:
                 tel.registry.histogram("serving_queue_wait_seconds").observe(
-                    t0 - sub)
+                    sp.t0 - sub)
                 tel.registry.histogram("serving_ttft_seconds").observe(
-                    now - sub)
+                    sp.t0 + sp.seconds - sub)
             tel.registry.counter("serving_tokens_generated_total").inc()
-            if tel.tracer is not None:
-                tel.tracer.complete("engine", "prefill", "engine", t0, now,
-                                    args={"req": req.id,
-                                          "tokens": len(req.prompt)})
             if tel.requests is not None:
                 tel.requests.transition(req.id, "decoding")
             self._note_compiles()
@@ -488,36 +485,34 @@ class GenerationEngine:
         the continuation is bit-identical to an unpreempted run.  A
         mid-prefill record re-enters the prefill phase at
         ``st.prefill_pos`` instead of rejoining the decode batch."""
-        tel, t0 = self.tel, time.perf_counter()
-        self.cache = self.paged.attach_slot(self.cache, slot, st.pages,
-                                            st.skip)
-        self.cache = self.paged.fault(self.cache, slot)
-        if st.state:
-            self.cache = self.paged.restore_slot_state(self.cache, slot,
-                                                       st.state)
-        if self.spec_on and st.draft_state is not None:
-            # reinstall the paired draft-cache row (bit-exact: the state
-            # never left its original bit pattern on the host)
-            self._draft_restore(slot, st.draft_state)
-        self.cache = dict(self.cache)
-        self.cache["cur_len"] = self.cache["cur_len"].at[slot].set(
-            st.host_len)
-        self._host_len[slot] = st.host_len
-        if st.prefill_pos is not None:
-            self._prefill_pos[slot] = st.prefill_pos
-            self._prefill_order.append(slot)
-        else:
-            self.last_tok = self.last_tok.at[slot, 0].set(st.last_tok)
-        self.slots[slot] = st.req
-        self.scheduler.n_resumed += 1
+        tel = self.tel
+        with span("engine.resume", self.tracer, req=st.req.id,
+                  slot=slot) as sp:
+            self.cache = self.paged.attach_slot(self.cache, slot, st.pages,
+                                                st.skip)
+            self.cache = self.paged.fault(self.cache, slot)
+            if st.state:
+                self.cache = self.paged.restore_slot_state(self.cache, slot,
+                                                           st.state)
+            if self.spec_on and st.draft_state is not None:
+                # reinstall the paired draft-cache row (bit-exact: the
+                # state never left its original bit pattern on the host)
+                self._draft_restore(slot, st.draft_state)
+            self.cache = dict(self.cache)
+            self.cache["cur_len"] = self.cache["cur_len"].at[slot].set(
+                st.host_len)
+            self._host_len[slot] = st.host_len
+            if st.prefill_pos is not None:
+                self._prefill_pos[slot] = st.prefill_pos
+                self._prefill_order.append(slot)
+            else:
+                self.last_tok = self.last_tok.at[slot, 0].set(st.last_tok)
+            self.slots[slot] = st.req
+            self.scheduler.n_resumed += 1
         if tel is not None:
-            now = time.perf_counter()
             tel.registry.counter("serving_resumed_total").inc()
             tel.registry.histogram("serving_resume_seconds").observe(
-                now - t0)
-            if tel.tracer is not None:
-                tel.tracer.complete("engine", "resume", "engine", t0, now,
-                                    args={"req": st.req.id, "slot": slot})
+                sp.seconds)
             if tel.requests is not None:
                 tel.requests.transition(
                     st.req.id, ("prefilling" if st.prefill_pos is not None
@@ -529,47 +524,43 @@ class GenerationEngine:
         priority class).  Returns False — with the engine state intact —
         when the swap store cannot take the pages."""
         req = self.slots[slot]
-        tel, t0 = self.tel, time.perf_counter()
+        tel = self.tel
         store = self.paged.swap
         traffic = (store.swap_out_bytes, store.swap_in_bytes,
                    store.n_swap_out, store.n_swap_in)
-        try:
-            self.cache = self.paged.evict(self.cache, slot)
-        except SwapExhausted:
-            # roll back any partially evicted pages (their device space
-            # was just freed, so the fault cannot itself run dry), and
-            # un-count the aborted attempt so the monitor only reports
-            # swapping that actually happened
-            self.cache = self.paged.fault(self.cache, slot)
-            (store.swap_out_bytes, store.swap_in_bytes,
-             store.n_swap_out, store.n_swap_in) = traffic
-            store.sync_registry()
-            if tel is not None and tel.tracer is not None:
-                tel.tracer.instant("engine", "preempt_aborted",
-                                   args={"req": req.id, "slot": slot})
-            return False
-        state = self.paged.snapshot_slot_state(self.cache, slot)
-        pages, skip = self.paged.detach_slot(slot)
-        st = Preempted(req=req, pages=pages, skip=skip, state=state,
-                       host_len=self._host_len[slot],
-                       last_tok=int(self.last_tok[slot, 0]),
-                       prefill_pos=self._prefill_pos.get(slot),
-                       draft_state=(self._draft_snapshot(slot)
-                                    if self.spec_on else None))
-        if slot in self._prefill_pos:       # preempted mid-prefill
-            del self._prefill_pos[slot]
-            self._prefill_order.remove(slot)
-        self.slots[slot] = None
-        self.scheduler.n_preempted += 1
-        self.scheduler.requeue(st)
+        with span("engine.preempt", self.tracer, req=req.id,
+                  slot=slot) as sp:
+            try:
+                self.cache = self.paged.evict(self.cache, slot)
+            except SwapExhausted:
+                # roll back any partially evicted pages (their device
+                # space was just freed, so the fault cannot itself run
+                # dry), and un-count the aborted attempt so the monitor
+                # only reports swapping that actually happened
+                self.cache = self.paged.fault(self.cache, slot)
+                (store.swap_out_bytes, store.swap_in_bytes,
+                 store.n_swap_out, store.n_swap_in) = traffic
+                store.sync_registry()
+                sp.done(aborted=1)
+                return False
+            state = self.paged.snapshot_slot_state(self.cache, slot)
+            pages, skip = self.paged.detach_slot(slot)
+            st = Preempted(req=req, pages=pages, skip=skip, state=state,
+                           host_len=self._host_len[slot],
+                           last_tok=int(self.last_tok[slot, 0]),
+                           prefill_pos=self._prefill_pos.get(slot),
+                           draft_state=(self._draft_snapshot(slot)
+                                        if self.spec_on else None))
+            if slot in self._prefill_pos:       # preempted mid-prefill
+                del self._prefill_pos[slot]
+                self._prefill_order.remove(slot)
+            self.slots[slot] = None
+            self.scheduler.n_preempted += 1
+            self.scheduler.requeue(st)
         if tel is not None:
-            now = time.perf_counter()
             tel.registry.counter("serving_preempted_total").inc()
             tel.registry.histogram("serving_preempt_seconds").observe(
-                now - t0)
-            if tel.tracer is not None:
-                tel.tracer.complete("engine", "preempt", "engine", t0, now,
-                                    args={"req": req.id, "slot": slot})
+                sp.seconds)
             if tel.requests is not None:
                 tel.requests.transition(req.id, "preempted")
         return True
@@ -582,32 +573,37 @@ class GenerationEngine:
         and the admission-victim hunt stands down (preempting for a
         request we cannot prefill yet would only flap)."""
         sched = self.scheduler
-        while True:
-            progress = False
-            for slot in range(self.max_batch):
-                if self.slots[slot] is not None:
+        with span("engine.admit", self.tracer,
+                  waiting=sched.waiting) as sp:
+            admitted = 0
+            while True:
+                progress = False
+                for slot in range(self.max_batch):
+                    if self.slots[slot] is not None:
+                        continue
+                    item = sched.pick(slot, prefill_budget)
+                    if item is None:
+                        continue
+                    if isinstance(item, Preempted):
+                        self._resume(slot, item)
+                    elif self.prefill_chunk:
+                        self._start_chunked(slot, item)
+                    else:
+                        self._start(slot, item)
+                    progress = True
+                    admitted += 1
+                if progress:
                     continue
-                item = sched.pick(slot, prefill_budget)
-                if item is None:
-                    continue
-                if isinstance(item, Preempted):
-                    self._resume(slot, item)
-                elif self.prefill_chunk:
-                    self._start_chunked(slot, item)
-                else:
-                    self._start(slot, item)
-                progress = True
-            if progress:
-                continue
-            head = sched.head()
-            if head is None:
-                break
-            if (prefill_budget is not None and prefill_budget <= 0
-                    and sched.prefill_tokens(head) > 0):
-                break
-            victim = sched.admission_victim(self.slots, head)
-            if victim is None or not self._preempt(victim):
-                break
+                head = sched.head()
+                if head is None:
+                    break
+                if (prefill_budget is not None and prefill_budget <= 0
+                        and sched.prefill_tokens(head) > 0):
+                    break
+                victim = sched.admission_victim(self.slots, head)
+                if victim is None or not self._preempt(victim):
+                    break
+            sp.done(admitted=admitted)
         if (sched.waiting and self.paged is not None
                 and not (prefill_budget is not None and prefill_budget <= 0)
                 and not any(s is not None for s in self.slots)):
@@ -725,7 +721,6 @@ class GenerationEngine:
         last token is the ``j``-th emission, which the draft consumes
         at the start of the *next* round."""
         k, tel = self.spec_k, self.tel
-        t0 = time.perf_counter()
         # grow every slot's page list to cover its whole verify window
         # *before* drafting: ensure-with-pressure can preempt another
         # active slot, and a victim's paired draft row must be stashed
@@ -846,15 +841,6 @@ class GenerationEngine:
                     or len(req.prompt) + len(req.out_tokens)
                     >= self.max_len):
                 self._finish(s, req)
-        if tel is not None:
-            now = time.perf_counter()
-            tel.registry.histogram("serving_decode_step_seconds").observe(
-                now - t0)
-            if tel.tracer is not None:
-                tel.tracer.complete("engine", "spec_round", "engine", t0,
-                                    now, args={"step": self.steps,
-                                               "active": len(active)})
-            self._note_compiles()
 
     def spec_counters(self) -> dict:
         """Host-side speculative-decoding counters (mirrored into the
@@ -930,9 +916,10 @@ class GenerationEngine:
             toks = jnp.asarray(list(part) + [0] * (C - n),
                                jnp.int32)[None, :]
             cache_in, stash = self._maybe_strip()
-            tc0 = time.perf_counter()
-            logits, new_cache = self._chunk(self.params, toks, cache_in,
-                                            slot, n)
+            with span("engine.prefill_chunk", self.tracer, slot=slot,
+                      tokens=n) as sp:
+                logits, new_cache = self._chunk(self.params, toks,
+                                                cache_in, slot, n)
             self.last_logits = logits
             self.cache = (restore_cold(new_cache, stash) if stash
                           else new_cache)
@@ -948,8 +935,7 @@ class GenerationEngine:
             tel = self.tel
             if tel is not None:
                 tel.registry.histogram(
-                    "serving_prefill_chunk_seconds").observe(
-                        time.perf_counter() - tc0)
+                    "serving_prefill_chunk_seconds").observe(sp.seconds)
             if pos + n >= len(req.prompt):      # final chunk: first token
                 tok = self._sample_one(logits, req)
                 req.out_tokens.append(int(tok))
@@ -976,25 +962,23 @@ class GenerationEngine:
         first chunks in the same step.  Returns tokens spent."""
         budget = self.prefill_budget
         spent = 0
-        t0 = time.perf_counter()
         self._stalled_ids.clear()
-        while True:
-            for slot in list(self._prefill_order):
-                if spent >= budget:
+        with span("engine.prefill_phase", self.tracer) as sp:
+            while True:
+                for slot in list(self._prefill_order):
+                    if spent >= budget:
+                        break
+                    if (self.slots[slot] is not None
+                            and slot in self._prefill_pos):
+                        spent += self._advance_prefill(slot, budget - spent)
+                before = len(self._prefill_order)
+                had_free = any(s is None for s in self.slots)
+                self._admit(prefill_budget=budget - spent)
+                if len(self._prefill_order) == before or spent >= budget \
+                        or not had_free:
                     break
-                if self.slots[slot] is not None and slot in self._prefill_pos:
-                    spent += self._advance_prefill(slot, budget - spent)
-            before = len(self._prefill_order)
-            had_free = any(s is None for s in self.slots)
-            self._admit(prefill_budget=budget - spent)
-            if len(self._prefill_order) == before or spent >= budget \
-                    or not had_free:
-                break
+            sp.done(tokens=spent)
         if spent and self.tel is not None:
-            if self.tel.tracer is not None:
-                self.tel.tracer.complete("engine", "prefill_phase",
-                                         "engine", t0,
-                                         args={"tokens": spent})
             self._note_compiles()
         return spent
 
@@ -1021,6 +1005,10 @@ class GenerationEngine:
         """One engine step: budgeted prefill work (chunked mode), then
         one batched decode step for the decode-phase slots.  Returns
         False when idle."""
+        with span("engine.step", self.tracer, step=self.steps):
+            return self._step()
+
+    def _step(self) -> bool:
         if self.prefill_chunk:
             prefill_spent = self._prefill_phase()
         else:
@@ -1036,123 +1024,131 @@ class GenerationEngine:
                 return True         # prefill in flight, nothing to decode
             return self.scheduler.waiting > 0
         if self.paged is not None:
-            for s in active:   # grow page lists to cover this step's write
-                if self.slots[s] is not None and s not in self._prefill_pos:
-                    self._ensure_with_pressure(s)
-                    if self.prefix_sharing:
-                        # CoW safety invariant for the decode write (a
-                        # structural no-op: decode writes land past the
-                        # prompt, and full prompt blocks are the only
-                        # shareable ones)
-                        self.cache = self.paged.make_writable(
-                            self.cache, s, self._host_len[s],
-                            self._host_len[s])
-            active = [s for s in range(self.max_batch)
-                      if self.slots[s] is not None
-                      and s not in self._prefill_pos]
-            # fault-before-gather: the decode step must never see a
-            # swapped page of an active slot (normally a no-op; resume
-            # already faults, and whole-request preemption only swaps
-            # vacated slots)
-            for s in active:
-                if self.paged.has_swapped(s):
-                    self.cache = self.paged.fault(self.cache, s)
+            with span("kv.ensure", self.tracer, rows=len(active)):
+                active = self._ensure_pages(active)
         if self.spec_on:
             # speculative mode replaces the single decode step with a
             # draft/verify round (1..k+1 tokens per slot); chunked
             # prefill is gated off, so no mid-prefill rows exist here
-            self._spec_round(active)
+            with span("engine.spec_round", self.tracer, step=self.steps,
+                      rows=len(active)) as sp:
+                self._spec_round(active)
+            if self.tel is not None:
+                self.tel.registry.histogram(
+                    "serving_decode_step_seconds").observe(sp.seconds)
+                self._note_compiles()
             self.steps += 1
-            if self.paged is not None and self.paged.compress:
-                for s in range(self.max_batch):
-                    if self.slots[s] is not None:
-                        self.cache = self.paged.compress_cold_pages(
-                            self.cache, s, self._host_len[s])
-            self._record_monitor()
-            self._sample_gauges()
-            return True
-        # while nothing is cold, run the decode variant without the cold
-        # pool (its in-graph entropy decode would be pure waste)
-        t_dec = time.perf_counter()
-        cache_in, stash = self._maybe_strip()
-        logits, new_cache = self._decode(self.params, self.last_tok,
-                                         cache_in)
-        self.last_logits = logits
-        self.cache = (restore_cold(new_cache, stash) if stash
-                      else new_cache)
-        self.steps += 1
-        if self._prefill_pos:
-            # mid-prefill rows decoded as masked garbage: the batched
-            # step advanced every timeline, so roll theirs back (their
-            # stray write sits at the next chunk's first position and is
-            # overwritten by it)
-            idx = jnp.asarray(sorted(self._prefill_pos), jnp.int32)
-            self.cache = dict(self.cache)
-            self.cache["cur_len"] = self.cache["cur_len"].at[idx].add(-1)
-        if prefill_spent:
-            self.n_interleaved_steps += 1
-        toks = np.asarray(greedy(logits))  # (B, 1)
-        # one batched draw for every sampled row: per-row keys fold
-        # (rng_seed, request.id, position) — identical values to calling
-        # _sample_one row by row, without k eager dispatches per step
-        samp = [s for s in active if self.slots[s].temperature > 0]
-        sampled = {}
-        if samp:
-            rows = logits[jnp.asarray(samp)]                  # (k, 1, V)
-            ids = jnp.asarray([self.slots[s].id for s in samp], jnp.int32)
-            pos = jnp.asarray([len(self.slots[s].out_tokens) for s in samp],
-                              jnp.int32)
-            temps = jnp.asarray([self.slots[s].temperature for s in samp],
-                                jnp.float32)
-
-            def draw(row, i, p, t):
-                key = request_key(self.rng0, i, p)
-                return sample_logits(row[None] / t, key,
-                                     temperature=1.0)[0, 0]
-
-            got = np.asarray(jax.vmap(draw)(rows, ids, pos, temps))
-            sampled = dict(zip(samp, got.tolist()))
-        tel = self.tel
-        if tel is not None:
-            # one timing feeds the step histogram and the straggler
-            # monitor (np.asarray above materialized the device work)
-            now = time.perf_counter()
-            dt = now - t_dec
-            tel.registry.histogram("serving_decode_step_seconds").observe(dt)
-            sstat = self._straggler.observe(dt, self.steps)
-            tel.registry.gauge("serving_decode_step_ewma_seconds").set(
-                self._straggler.ewma_seconds)
-            if sstat.is_straggler:
-                tel.registry.counter("serving_decode_straggler_total").inc()
-                if tel.tracer is not None:
-                    tel.tracer.instant("engine", "decode_straggler",
-                                       args={"step": self.steps,
-                                             "z": sstat.z, "seconds": dt})
-            if tel.tracer is not None:
-                tel.tracer.complete("engine", "decode_step", "engine",
-                                    t_dec, now,
-                                    args={"step": self.steps,
-                                          "active": len(active)})
-            tel.registry.counter("serving_tokens_generated_total").inc(
-                len(active))
-            self._note_compiles()
-        for s in active:
-            req = self.slots[s]
-            t = int(toks[s, 0] if req.temperature <= 0 else sampled[s])
-            req.out_tokens.append(t)
-            self.last_tok = self.last_tok.at[s, 0].set(t)
-            self._host_len[s] += 1
-            if len(req.out_tokens) >= req.max_new_tokens or (
-                    len(req.prompt) + len(req.out_tokens) >= self.max_len):
-                self._finish(s, req)
+        else:
+            self._decode_step(active, prefill_spent)
         if self.paged is not None and self.paged.compress:
-            for s in range(self.max_batch):
-                if self.slots[s] is not None:
-                    self.cache = self.paged.compress_cold_pages(
-                        self.cache, s, self._host_len[s])
+            self.cache = self.paged.compress_cold(
+                self.cache, [(s, self._host_len[s])
+                             for s in range(self.max_batch)
+                             if self.slots[s] is not None])
         self._record_monitor()
         self._sample_gauges()
         return True
+
+    def _ensure_pages(self, active) -> list:
+        """Grow the page lists of the decode-phase slots to cover this
+        step's write and fault back any swapped page; returns the slots
+        still active (growth may preempt)."""
+        for s in active:
+            if self.slots[s] is not None and s not in self._prefill_pos:
+                self._ensure_with_pressure(s)
+                if self.prefix_sharing:
+                    # CoW safety invariant for the decode write (a
+                    # structural no-op: decode writes land past the
+                    # prompt, and full prompt blocks are the only
+                    # shareable ones)
+                    self.cache = self.paged.make_writable(
+                        self.cache, s, self._host_len[s],
+                        self._host_len[s])
+        active = [s for s in range(self.max_batch)
+                  if self.slots[s] is not None
+                  and s not in self._prefill_pos]
+        # fault-before-gather: the decode step must never see a swapped
+        # page of an active slot (normally a no-op; resume already
+        # faults, and whole-request preemption only swaps vacated slots)
+        for s in active:
+            if self.paged.has_swapped(s):
+                self.cache = self.paged.fault(self.cache, s)
+        return active
+
+    def _decode_step(self, active, prefill_spent: int) -> None:
+        """One batched decode step for ``active``, then the commit of
+        each row's token.  The step's input cache dies with this frame,
+        before the caller codes pages, so coding keeps no dead copy of
+        the cache on the device."""
+        tr = self.tracer
+        with span("engine.decode_step", tr, step=self.steps,
+                  rows=len(active)) as sp:
+            with span("engine.dispatch", tr):
+                # while nothing is cold, run the decode variant without
+                # the cold pool (its in-graph entropy decode would be
+                # pure waste)
+                cache_in, stash = self._maybe_strip()
+                logits, new_cache = self._decode(self.params, self.last_tok,
+                                                 cache_in)
+                self.last_logits = logits
+                self.cache = (restore_cold(new_cache, stash) if stash
+                              else new_cache)
+                if self._prefill_pos:
+                    # mid-prefill rows decoded as masked garbage: the
+                    # batched step advanced every timeline, so roll theirs
+                    # back (their stray write sits at the next chunk's
+                    # first position and is overwritten by it)
+                    idx = jnp.asarray(sorted(self._prefill_pos), jnp.int32)
+                    self.cache = dict(self.cache)
+                    self.cache["cur_len"] = \
+                        self.cache["cur_len"].at[idx].add(-1)
+            self.steps += 1
+            if prefill_spent:
+                self.n_interleaved_steps += 1
+            with span("engine.sync", tr):
+                toks = np.asarray(greedy(logits))  # (B, 1)
+            # one batched draw for every sampled row: per-row keys fold
+            # (rng_seed, request.id, position) — identical values to
+            # calling _sample_one row by row, without k eager dispatches
+            samp = [s for s in active if self.slots[s].temperature > 0]
+            sampled = {}
+            if samp:
+                with span("engine.sample", tr, rows=len(samp)):
+                    sampled = self._sample_rows(logits, samp)
+        tel = self.tel
+        if tel is not None:
+            tel.registry.histogram("serving_decode_step_seconds").observe(
+                sp.seconds)
+            tel.registry.counter("serving_tokens_generated_total").inc(
+                len(active))
+            self._note_compiles()
+        with span("engine.commit", tr):
+            for s in active:
+                req = self.slots[s]
+                t = int(toks[s, 0] if req.temperature <= 0 else sampled[s])
+                req.out_tokens.append(t)
+                self.last_tok = self.last_tok.at[s, 0].set(t)
+                self._host_len[s] += 1
+                if len(req.out_tokens) >= req.max_new_tokens or (
+                        len(req.prompt) + len(req.out_tokens)
+                        >= self.max_len):
+                    self._finish(s, req)
+
+    def _sample_rows(self, logits, samp) -> dict:
+        """slot -> sampled token for the rows ``samp`` of ``logits``."""
+        rows = logits[jnp.asarray(samp)]                      # (k, 1, V)
+        ids = jnp.asarray([self.slots[s].id for s in samp], jnp.int32)
+        pos = jnp.asarray([len(self.slots[s].out_tokens) for s in samp],
+                          jnp.int32)
+        temps = jnp.asarray([self.slots[s].temperature for s in samp],
+                            jnp.float32)
+
+        def draw(row, i, p, t):
+            key = request_key(self.rng0, i, p)
+            return sample_logits(row[None] / t, key, temperature=1.0)[0, 0]
+
+        got = np.asarray(jax.vmap(draw)(rows, ids, pos, temps))
+        return dict(zip(samp, got.tolist()))
 
     def _record_monitor(self):
         if self.kv_monitor is None or self.paged is None:

@@ -13,6 +13,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 
 import numpy as np
 import jax
@@ -22,7 +23,7 @@ from repro.configs import get, smoke_variant
 from repro.models import model as M
 from repro.runtime.monitor import KVCacheMonitor, StragglerMonitor
 from repro.runtime.tracing import (ENGINE_TRACK, RequestStateTracker,
-                                   SpanTracer, request_track)
+                                   SpanTracer, request_track, span)
 from repro.runtime.trace_export import (build_trace, export_chrome_trace,
                                         validate_chrome_trace)
 from repro.serving import EngineConfig, GenerationEngine, Request
@@ -302,6 +303,129 @@ def test_metrics_only_mode_keeps_no_event_buffer():
     toks, _ = _serve(params, cfg, _anchor_requests(), telemetry=tel,
                      cache_mode="paged")
     assert tel.registry.get("serving_ttft_seconds").count == 4
+
+
+# --------------------------------------------------------------------------
+# spans on the profiler's clock, named device scopes
+# --------------------------------------------------------------------------
+
+def _coding_requests():
+    """Prompts that fill pages of 8, so full pages go cold while they
+    decode."""
+    return [Request(prompt=list(range(1, 20)), max_new_tokens=6, id=9_300),
+            Request(prompt=list(range(30, 42)), max_new_tokens=7, id=9_301),
+            Request(prompt=[5, 6, 7], max_new_tokens=4, id=9_302)]
+
+
+_CODING = dict(cache_mode="paged", page_size=8, compress_cold=True)
+
+
+def _host_spans(logdir) -> list:
+    """(name, start_ns, end_ns, stats) of the engine's spans, from the
+    host thread that holds them."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(logdir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+                   for ev in line.events
+                   if ev.name.startswith(("engine.", "kv.", "swap."))]
+            if any(n == "engine.step" for n, *_ in evs):
+                out += evs
+    return out
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def test_engine_spans_on_the_profiler_clock(tmp_path):
+    """A paged engine that codes cold pages, stepped under the profiler:
+    the trace holds each ``engine.step`` with its decode step (dispatch
+    and sync), commit and page coding nested in it, and the coded-page
+    count; the tracer buffer gets the same spans; tokens are the same
+    with the profiler on and off."""
+    cfg = smoke_variant(get("qwen3-8b"))
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    bare, _ = _serve(params, cfg, _coding_requests(), **_CODING)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        traced, _ = _serve(params, cfg, _coding_requests(), **_CODING)
+    finally:
+        jax.profiler.stop_trace()
+    assert traced == bare
+
+    evs = _host_spans(tmp_path)
+    steps = [e for e in evs if e[0] == "engine.step"]
+    assert steps
+
+    def kids(parent, name):
+        return [e for e in evs if e[0] == name and _inside(e, parent)]
+
+    full = [st for st in steps if kids(st, "engine.decode_step")]
+    assert full
+    for st in full:
+        dec, = kids(st, "engine.decode_step")
+        assert dec[3]["rows"] >= 1
+        assert len(kids(dec, "engine.dispatch")) == 1
+        assert len(kids(dec, "engine.sync")) == 1
+        assert len(kids(st, "engine.commit")) == 1
+        assert len(kids(st, "kv.code_pages")) == 1
+    coded = sum(e[3]["pages"] for e in evs if e[0] == "kv.code_pages.done")
+    pages = [e for e in evs if e[0] == "kv.code_page"]
+    assert coded > 0 and len(pages) >= coded
+    for page in pages:
+        assert {"slot", "page"} <= set(page[3])
+        for part in ("kv.page_fetch", "kv.page_encode"):
+            assert len(kids(page, part)) == 1
+
+    tel = Telemetry()
+    instr, _ = _serve(params, cfg, _coding_requests(), telemetry=tel,
+                      **_CODING)
+    assert instr == bare
+    buffered = {f"{cat}.{name}" for ph, cat, name, track, *_ in
+                tel.tracer.events if ph == "X" and track == ENGINE_TRACK}
+    assert buffered == {e[0] for e in evs if not e[0].endswith(".done")}
+    code = [a for ph, cat, name, *_, a in tel.tracer.events
+            if (cat, name) == ("kv", "code_pages")]
+    assert sum(a["pages"] for a in code) == coded
+
+
+def test_span_closes_when_its_body_raises():
+    tr = SpanTracer()
+    with pytest.raises(RuntimeError):
+        with span("swap.fault", tr, slot=3):
+            raise RuntimeError("out of pages")
+    (ph, cat, name, track, _, dur, args), = tr.events
+    assert (ph, cat, name, args) == ("X", "swap", "fault", {"slot": 3})
+    assert dur >= 0
+
+
+def test_decode_step_carries_the_device_scopes(monkeypatch):
+    """The lowered decode step of compressed weights over a cache with a
+    cold pool names the cold-page decode, its permutation and the weight
+    decode in its ops' metadata (the scopes a TPU trace reports as each
+    op's ``tf_op``)."""
+    import jax.numpy as jnp
+    import repro.kvcache.paged as PG
+    from repro.core.store import compress_tree
+    from repro.kvcache import PagedKVCache
+    # the TPU's branch of the in-graph cold decode (kernels.decode_pages)
+    monkeypatch.setattr(PG, "interpret_mode", lambda: False)
+    cfg = smoke_variant(get("qwen3-8b"))
+    params, _ = compress_tree(M.init_params(jax.random.PRNGKey(0), cfg),
+                              fmt="tpu", min_elems=2048)
+    pkv = PagedKVCache(cfg, 2, 32, dtype=jnp.float32, page_size=8,
+                       compress_cold=True)
+    tok = jnp.zeros((2, 1), jnp.int32)
+    text = jax.jit(lambda p, t, c: M.decode_step(p, cfg, t, c)).lower(
+        params, tok, pkv.init_cache()).as_text(debug_info=True)
+    for scope in ("kv.cold_decode", "kv.cold_permute", "weights.decode"):
+        # a location's name path: "<scope>/op" or ".../<scope>/op"
+        assert re.search(rf'["/]{re.escape(scope)}/', text), scope
 
 
 # --------------------------------------------------------------------------
