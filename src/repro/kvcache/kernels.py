@@ -8,12 +8,17 @@ kernel (``kernels/ecf8_decode.py``) generalized for cache pages:
     exponent field), so the peek is ``max_len`` bits and the window
     refills **up to two bytes** per round (vs one for 8-bit codes);
   * decode tables are **per page** (every page carries its own canonical
-    codebook) — all pages' (3, L) tables are scalar-prefetched into SMEM
-    and each grid cell reads its own rows as scalars;
-  * the kernel emits *canonical symbol indices*; the (up to 256-entry)
-    canonical permutation and the sign/mantissa fuse are applied by the
-    caller as plain XLA gathers (``codec.assemble_pages_jnp``) — a
-    256-way in-register select would cost more than it saves.
+    codebook) — all pages' (3, L) tables and canonical permutations are
+    scalar-prefetched into SMEM and each grid cell reads its own rows as
+    scalars; the permutation travels packed four 8-bit symbols to an
+    int32 word, so a 256-symbol page costs 64 words;
+  * once the rounds have filled the page's (S, 128) block of canonical
+    indices, the kernel maps the whole block to exponent symbols with one
+    compare/select per permutation entry (16 for fp8 pages, 256 for
+    bf16/f32), as the weight kernel applies its 16-entry permutation —
+    vector selects, where an XLA gather would run element by element.
+    The caller only merges the sign/mantissa plane
+    (``codec.assemble_pages_jnp``).
 
 VMEM per cell: payload (stride x 128) + output (S x 128 x 4B), both far
 inside budget for realistic page sizes (<= 64K elements).
@@ -33,8 +38,9 @@ from . import codec
 from .codec import LANES
 
 
-def _decode_page_kernel(tab_ref, payload_ref, out_ref, *,
-                        sym_per_lane: int, stride: int, max_len: int):
+def _decode_page_kernel(tab_ref, perm_ref, payload_ref, out_ref, *,
+                        sym_per_lane: int, stride: int, max_len: int,
+                        n_sym: int):
     S, L = sym_per_lane, max_len
     # this page's (3, L) decode table rows, as SMEM scalars
     base = pl.program_id(0) * (3 * L)
@@ -81,49 +87,82 @@ def _decode_page_kernel(tab_ref, payload_ref, out_ref, *,
 
     jax.lax.fori_loop(0, S, round_fn, (win, byteptr, bits_valid))
 
+    # canonical index -> exponent symbol over the whole (S, LANES) block:
+    # one select per permutation entry, indices clipped as the reference
+    # gather's mode="clip" does; word j of the page's packed permutation
+    # holds entries 4j .. 4j + 3, one byte each
+    idx = jnp.clip(out_ref[0], 0, n_sym - 1)
+    n_words = -(-n_sym // 4)
+    pbase = pl.program_id(0) * n_words
 
-# pages per kernel call: 4096 pages x (3, 12) int32 tables = 576 KiB of SMEM
-_MAX_PAGES_PER_CALL = 4096
+    def perm_fn(j, sym):
+        word = perm_ref[pbase + j]
+        for b in range(4):
+            sym = jnp.where(idx == 4 * j + b, (word >> (8 * b)) & 0xFF, sym)
+        return sym
+
+    out_ref[0] = jax.lax.fori_loop(0, n_words, perm_fn, jnp.zeros_like(idx))
+
+
+def _pack_perm(perm):
+    """(N, n_sym) int32 permutations -> (N * ceil(n_sym / 4),) int32,
+    entry ``4j + b`` of a page in byte ``b`` of its word ``j``."""
+    N, n_sym = perm.shape
+    p = jnp.pad(perm.astype(jnp.int32) & 0xFF,
+                ((0, 0), (0, -n_sym % 4))).reshape(N, -1, 4)
+    return (p[..., 0] | (p[..., 1] << 8) | (p[..., 2] << 16)
+            | (p[..., 3] << 24)).reshape(-1)
+
+
+# pages per kernel call: the SMEM operands take (3, 12) int32 tables +
+# 64 packed permutation words = 400 B a bf16 page, 800 KiB at 2048 pages
+# (of 1 MiB)
+_MAX_PAGES_PER_CALL = 2048
 
 
 @functools.partial(jax.jit, static_argnames=("n_elem", "interpret"))
-def decode_page_indices_pallas(payload, tables, *, n_elem: int,
+def decode_page_indices_pallas(payload, tables, perm, *, n_elem: int,
                                interpret: bool | None = None):
-    """Decode canonical symbol indices for N pages.
+    """Decode the exponent symbols of N pages.
 
     Args:
       payload: (N, stride, LANES) uint8 zero-padded lane streams.
-      tables:  (N, 3, L) int32 — lj_limit / first_lj / offset per page,
-        handed to the kernel through scalar prefetch (SMEM).
+      tables:  (N, 3, L) int32 — lj_limit / first_lj / offset per page.
+      perm:    (N, n_symbols) int32 canonical symbol permutation per page.
+        Tables and permutations (packed four symbols a word) reach the
+        kernel through scalar prefetch (SMEM).
       interpret: Pallas interpret mode; ``None`` picks it from the
         backend (:func:`repro.kernels.interpret_mode`).
 
-    Returns (N, S, LANES) int32 canonical indices.
+    Returns (N, S, LANES) int32 exponent symbols.
     """
     N, stride, _ = payload.shape
-    if N > _MAX_PAGES_PER_CALL:   # the tables must fit in SMEM (1 MiB)
+    if N > _MAX_PAGES_PER_CALL:   # the SMEM operands must fit (1 MiB)
         return jnp.concatenate([
             decode_page_indices_pallas(
                 payload[i: i + _MAX_PAGES_PER_CALL],
-                tables[i: i + _MAX_PAGES_PER_CALL], n_elem=n_elem,
+                tables[i: i + _MAX_PAGES_PER_CALL],
+                perm[i: i + _MAX_PAGES_PER_CALL], n_elem=n_elem,
                 interpret=interpret)
             for i in range(0, N, _MAX_PAGES_PER_CALL)])
     L = tables.shape[-1]
     S = codec.sym_per_lane(n_elem)
     kernel = functools.partial(_decode_page_kernel, sym_per_lane=S,
-                               stride=stride, max_len=L)
+                               stride=stride, max_len=L,
+                               n_sym=perm.shape[-1])
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(N,),
             in_specs=[pl.BlockSpec((1, stride, LANES),
-                                   lambda c, tab: (c, 0, 0))],
-            out_specs=pl.BlockSpec((1, S, LANES), lambda c, tab: (c, 0, 0)),
+                                   lambda c, tab, prm: (c, 0, 0))],
+            out_specs=pl.BlockSpec((1, S, LANES),
+                                   lambda c, tab, prm: (c, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((N, S, LANES), jnp.int32),
         interpret=interpret_mode() if interpret is None else interpret,
-    )(tables.reshape(-1).astype(jnp.int32), payload)
+    )(tables.reshape(-1).astype(jnp.int32), _pack_perm(perm), payload)
 
 
 def decode_pages(payload, signmant, tables, perm, *, n_elem: int,
@@ -131,16 +170,18 @@ def decode_pages(payload, signmant, tables, perm, *, n_elem: int,
     """Full page decode via the Pallas kernel -> (N, n_elem) values.
 
     Same contract as ``codec.decode_pages_jnp`` (the pure-XLA oracle the
-    serving engine uses in-graph); this path routes the entropy decode
-    through the TPU kernel and fuses perm + sign/mantissa outside.  The
-    two parts carry the scopes ``kv.cold_decode`` and ``kv.cold_permute``
-    in their ops' metadata, so a profiler trace names them."""
+    serving engine uses in-graph) and bit-identical to it; the kernel
+    decodes exponent symbols, permutation included, and the
+    sign/mantissa merge follows in XLA.  The two parts carry the scopes
+    ``kv.cold_decode`` and ``kv.cold_permute`` in their ops' metadata, so
+    a profiler trace names them."""
     with jax.named_scope("kv.cold_decode"):
-        sym_idx = decode_page_indices_pallas(payload, tables, n_elem=n_elem,
-                                             interpret=interpret)
+        syms = decode_page_indices_pallas(payload, tables, perm,
+                                          n_elem=n_elem, interpret=interpret)
     with jax.named_scope("kv.cold_permute"):
-        return codec.finish_pages_jnp(sym_idx, signmant, perm,
-                                      n_elem=n_elem, dtype_name=dtype_name)
+        return codec.assemble_pages_jnp(
+            syms.reshape(syms.shape[0], -1)[:, :n_elem], signmant,
+            n_elem=n_elem, dtype_name=dtype_name)
 
 
 # (mesh, batch axes, n_elem, dtype, interpret) -> shard_map'ed decode;

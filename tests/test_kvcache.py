@@ -100,6 +100,61 @@ def test_kernel_matches_jnp_and_oracle():
             np.asarray(codec.decode_page(c)).view(np.uint16), want)
 
 
+# (exponent bits, sign shift, mantissa bits) of each page dtype's bit view
+_FIELDS = {"float8_e4m3fn": (4, 7, 3), "bfloat16": (8, 15, 7),
+           "float32": (8, 31, 23)}
+
+
+def _page_with_n_exponents(rng, dtype_name, k, n):
+    """An ``n``-element page whose exponent field takes ``k`` distinct
+    values (every field, Inf/NaN included, when ``k`` is all of them);
+    sign and mantissa bits random."""
+    exp_bits, sign_at, mant_bits = _FIELDS[dtype_name]
+    uint, view = _VIEW[dtype_name]
+    fields = rng.permutation(1 << exp_bits)[:k]
+    exp = np.concatenate([fields, rng.choice(fields, n - k)])
+    sign = rng.integers(0, 2, n).astype(np.uint64)
+    mant = rng.integers(0, 1 << mant_bits, n, dtype=np.uint64)
+    bits = (sign << sign_at) | (exp.astype(np.uint64) << mant_bits) | mant
+    return bits.astype(uint).view(view)
+
+
+@pytest.mark.parametrize("dtype_name", list(_VIEW))
+def test_kernel_permutes_every_active_symbol_count(dtype_name):
+    """The kernel's in-register canonical permutation == the jnp decode's
+    gather == the host oracle, bit for bit, on pages whose active-symbol
+    count runs from 1 to every exponent field, beside an all-zero
+    never-written cold slot in the same batch."""
+    n_sym = 1 << _FIELDS[dtype_name][0]
+    uint, _ = _VIEW[dtype_name]
+    rng = np.random.default_rng(n_sym)
+    counts = sorted({1, 2, 3, n_sym // 2 + 1, n_sym - 1, n_sym})
+    pages = [_page_with_n_exponents(rng, dtype_name, k, 2048)
+             for k in counts]
+    cps = [codec.encode_page(p) for p in pages]
+    assert [c.n_active for c in cps] == counts
+    n = len(cps) + 1                     # the last slot was never written
+    pay = np.zeros((n, max(c.stride for c in cps), codec.LANES), np.uint8)
+    for i, c in enumerate(cps):
+        pay[i, : c.stride] = c.payload
+    sm = np.zeros((n, cps[0].signmant.size), np.uint8)
+    tab = np.zeros((n, *cps[0].tables().shape), np.int32)
+    perm = np.zeros((n, n_sym), np.int32)
+    for i, c in enumerate(cps):
+        sm[i], tab[i], perm[i] = c.signmant, c.tables(), c.perm
+    args = tuple(jnp.asarray(a) for a in (pay, sm, tab, perm))
+    got_k = np.asarray(kernels.decode_pages(
+        *args, n_elem=2048, dtype_name=dtype_name, interpret=True)).view(uint)
+    got_j = np.asarray(codec.decode_pages_jnp(
+        *args, n_elem=2048, dtype_name=dtype_name)).view(uint)
+    np.testing.assert_array_equal(got_k, got_j)
+    for i, (p, c) in enumerate(zip(pages, cps)):
+        np.testing.assert_array_equal(got_k[i], p.view(uint))
+        np.testing.assert_array_equal(
+            np.asarray(codec.decode_page(c)).view(uint), p.view(uint))
+    np.testing.assert_array_equal(got_k[-1], 0)
+
+
 def test_kernel_splits_page_batches_beyond_smem(monkeypatch):
     """Batches of more pages than one kernel call's SMEM table budget are
     decoded in several calls, bit-identical to the jnp decode."""

@@ -47,13 +47,48 @@ def _compiles_kernel(fn, *args):
     return compiled
 
 
+# the qwen3-8b.decode cell's cold pool: 2048 slots of 8 x 16 x 128 bf16,
+# a 128-byte stride budget, 12-bit codes over 256 exponent symbols
+_POOL = 2048
+_PAGE_ELEMS = 8 * 16 * 128                      # kv heads x page x head_dim
+
+
+def _cold_pool(sds):
+    return (sds((_POOL, 128, LANES), jnp.uint8),
+            sds((_POOL, _PAGE_ELEMS), jnp.uint8),
+            sds((_POOL, 3, 12), jnp.int32), sds((_POOL, 256), jnp.int32))
+
+
 def test_page_decode_kernel_compiles(sds):
+    """The whole pool in one call: its tables and packed permutations fit
+    the 1 MiB of SMEM."""
     from repro.kvcache import kernels
-    n_elem = 8 * 16 * 128                       # kv heads x page x head_dim
+    pay, _, tab, perm = _cold_pool(sds)
     _compiles_kernel(
-        lambda p, t: kernels.decode_page_indices_pallas(
-            p, t, n_elem=n_elem, interpret=False),
-        sds((64, 192, LANES), jnp.uint8), sds((64, 3, 12), jnp.int32))
+        lambda p, t, pr: kernels.decode_page_indices_pallas(
+            p, t, pr, n_elem=_PAGE_ELEMS, interpret=False),
+        pay, tab, perm)
+
+
+def test_page_decode_applies_the_permutation_without_a_gather(sds):
+    """The compiled cold-pool decode maps canonical indices to symbols in
+    the kernel: no XLA gather is left, which on a TPU runs element by
+    element.  The control, the permutation as the jnp decode's gather
+    (``codec.finish_pages_jnp``), compiles to one."""
+    import re
+    from repro.kvcache import codec, kernels
+    gather = re.compile(r"\bgather\(")
+    pool = _cold_pool(sds)
+    compiled = _compiles_kernel(
+        lambda *a: kernels.decode_pages(
+            *a, n_elem=_PAGE_ELEMS, dtype_name="bfloat16", interpret=False),
+        *pool)
+    assert not gather.search(compiled.as_text())
+    control = jax.jit(lambda i, sm, pr: codec.finish_pages_jnp(
+        i, sm, pr, n_elem=_PAGE_ELEMS, dtype_name="bfloat16")).lower(
+            sds((_POOL, _PAGE_ELEMS // LANES, LANES), jnp.int32), pool[1],
+            pool[3]).compile()
+    assert gather.search(control.as_text())
 
 
 def test_weight_decode_kernel_compiles(sds):
