@@ -42,28 +42,37 @@ def sample(records, seed: int, n: int) -> list:
 
 
 class ReferenceWeights:
-    """The checkpoint's fp8 matrices and ``--seed``'s norm gains on the
-    device, made by ``lib/weights`` from the configuration's weight seed
-    and the run seed: nothing the program made.  ``weights(name)`` is a
-    matrix, ``weights.gain(name)`` a gain vector."""
+    """The checkpoint's fp8 matrices and ``--seed``'s 1-D parameters on
+    the device, made by ``lib/weights`` from the configuration's weight
+    seed and the run seed, under the names of the reference ``ref``
+    (``tensor_specs``, ``param_specs``): nothing the program made.
+    ``weights(name)`` is a matrix, ``weights.param(name)`` a 1-D
+    parameter as drawn (a gain itself, not less one), and
+    ``weights.layer(l)`` both of layer ``l`` by their names after
+    ``layer{l}.``."""
 
-    def __init__(self, model: dict, weight_seed: int, seed: int):
+    def __init__(self, ref, model: dict, weight_seed: int, seed: int):
         import jax
-        host = W.synth(W.tensor_specs(model), weight_seed)
+        host = W.synth(ref.tensor_specs(model), weight_seed)
         self.arrays = {n: jax.device_put(host.pop(n)) for n in list(host)}
-        self.gains = {n: jax.device_put(W.gains(seed, n, k))
-                      for n, k in W.gain_specs(model)}
+        self.params = {n: jax.device_put(W.vector(seed, n, k, kind))
+                       for n, k, kind in ref.param_specs(model)}
 
     def __call__(self, name: str):
         return self.arrays[name]
 
-    def gain(self, name: str):
-        return self.gains[name]
+    def param(self, name: str):
+        return self.params[name]
+
+    def layer(self, l: int) -> dict:
+        p = f"layer{l}."
+        return {n[len(p):]: x for d in (self.arrays, self.params)
+                for n, x in d.items() if n.startswith(p)}
 
     def delete(self):
-        for x in [*self.arrays.values(), *self.gains.values()]:
+        for x in [*self.arrays.values(), *self.params.values()]:
             x.delete()
-        self.arrays, self.gains = {}, {}
+        self.arrays, self.params = {}, {}
 
 
 def weight_mismatches(params, lay, weights) -> int:

@@ -5,13 +5,19 @@ already encoded.  The harness keeps that checkpoint in
 ``bench/.ckpt/<key>/`` (git-ignored): written on a configuration's first
 run in a checkout, loaded by every later run.  The key covers the
 configuration's model and program sections, its ``weight_seed`` and the
-bytes of the encoder's source files and of this module, so a change to
-the format re-encodes and is never served a stale container.
+bytes of the encoder's source files, of this module, of
+``lib/weights.py`` and of the references that name the tensors
+(:func:`reference_files`), so a change to the format re-encodes and is
+never served a stale container.
 
 The cache holds the fp8 matrices only.  On every run the loader adds
-``--seed``'s norm gains (``lib/weights.py``) on the host, in the form the
-program's norms take (``1 + scale``), and puts the tree on the device:
-the containers are never touched, so no run re-encodes.
+``--seed``'s 1-D parameters on the host, in the form the program stores
+their kind in (``lib/weights.program_form``), and puts the tree on the
+device: the containers are never
+touched, so no run re-encodes.
+
+``ref`` below is the configuration's reference module: its
+``tensor_specs`` and ``param_specs`` name every tensor.
 """
 from __future__ import annotations
 
@@ -24,6 +30,7 @@ from pathlib import Path
 
 import numpy as np
 
+from . import spec
 from . import weights as W
 
 CACHE_DIR = Path(__file__).resolve().parents[1] / ".ckpt"
@@ -39,7 +46,7 @@ def _src_root() -> Path:
     return Path(store.__file__).resolve().parents[1]
 
 
-def cache_key(config: dict, src_root: Path | None = None) -> str:
+def cache_key(config: dict, ref, src_root: Path | None = None) -> str:
     src_root = _src_root() if src_root is None else src_root
     h = hashlib.sha256()
     h.update(json.dumps({k: config[k] for k in ("model", "program",
@@ -49,9 +56,19 @@ def cache_key(config: dict, src_root: Path | None = None) -> str:
     for rel in ENCODER_SOURCES:
         h.update(rel.encode())
         h.update((src_root / rel).read_bytes())
-    h.update(Path(__file__).read_bytes())
-    h.update(Path(W.__file__).read_bytes())
+    for path in (__file__, W.__file__, *reference_files(ref)):
+        h.update(Path(path).read_bytes())
     return f"{config['name']}-{h.hexdigest()[:16]}"
+
+
+def reference_files(ref) -> list:
+    """Every file a reference's declaration can come from: those beside
+    ``ref`` and those of ``bench/reference/``, which a reference elsewhere
+    (a test fixture's) may load parts of through ``spec.module``."""
+    dirs = [Path(ref.__file__).resolve().parent]
+    if (spec.BENCH / "reference").resolve() != dirs[0]:
+        dirs.append((spec.BENCH / "reference").resolve())
+    return [p for d in dirs for p in sorted(d.glob("*.py"))]
 
 
 # -- the program's parameter layout -----------------------------------------
@@ -63,8 +80,9 @@ def _path_names(path) -> list:
 def layout(cfg):
     """[(path names, shape, per-layer ckpt names, is weight)] for every
     leaf of the program's parameter tree, plus its treedef.  A leaf maps
-    to one checkpoint name per layer it stacks: a weight matrix of
-    ``weights.tensor_specs`` or a norm gain of ``weights.gain_specs``."""
+    to one checkpoint name per layer it stacks: a weight matrix of the
+    reference's ``tensor_specs`` where the leaf is 2-D a layer, else a 1-D
+    parameter of its ``param_specs``."""
     import jax
     from repro.models import model as M
     shapes = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg))
@@ -89,21 +107,22 @@ def layout(cfg):
     return out, treedef
 
 
-def _check_names(lay, model: dict) -> None:
-    for weight, want in ((True, W.tensor_specs(model)),
-                         (False, W.gain_specs(model))):
+def _check_names(lay, ref, model: dict) -> None:
+    for weight, want in ((True, ref.tensor_specs(model)),
+                         (False, ref.param_specs(model))):
         used = {n for _, _, ck, w in lay if w == weight for n in ck}
-        if used != {n for n, _ in want}:
+        names = {s[0] for s in want}
+        if used != names:
             raise ValueError(f"checkpoint tensors and program leaves "
-                             f"differ: {sorted(used ^ {n for n, _ in want})}")
+                             f"differ: {sorted(used ^ names)}")
 
 
-def fp8_leaves(cfg, model: dict, weight_seed: int):
+def fp8_leaves(cfg, ref, model: dict, weight_seed: int):
     """The base fp8 matrices in the program's layout, one host leaf at a
     time: yields (leaf index, path names, array)."""
-    specs = dict(W.tensor_specs(model))
+    specs = dict(ref.tensor_specs(model))
     lay, _ = layout(cfg)
-    _check_names(lay, model)
+    _check_names(lay, ref, model)
     for i, (names, shape, ck, weight) in enumerate(lay):
         if not weight:
             continue
@@ -119,21 +138,31 @@ def fp8_leaves(cfg, model: dict, weight_seed: int):
         yield i, names, arr
 
 
-def seeded_gains(cfg, seed: int) -> dict:
-    """{leaf index: the program's norm scale} for ``seed``: the gains of
-    ``lib/weights.gains`` less one, stacked as the program stacks them."""
+def seeded_params(cfg, ref, model: dict, seed: int) -> dict:
+    """{leaf index: the program's 1-D parameter} for ``seed``: the values
+    of ``lib/weights.program_form``, stacked as the program stacks
+    them."""
+    specs = {n: (k, kind) for n, k, kind in ref.param_specs(model)}
     lay, _ = layout(cfg)
+    _check_names(lay, ref, model)
     out = {}
     for i, (names, shape, ck, weight) in enumerate(lay):
-        if not weight:
-            g = np.stack([W.gains(seed, n, shape[-1]) for n in ck])
-            out[i] = (g - 1).reshape(shape)
+        if weight:
+            continue
+        rows = []
+        for n in ck:
+            k, kind = specs[n]
+            if k != shape[-1]:
+                raise ValueError(f"program leaf {'/'.join(names)} has "
+                                 f"length {shape[-1]}, parameter {n} {k}")
+            rows.append(W.program_form(seed, n, k, kind))
+        out[i] = np.stack(rows).reshape(shape)
     return out
 
 
 # -- the cache ----------------------------------------------------------------
 
-def _write(cfg, config: dict, path: Path, log) -> dict:
+def _write(cfg, ref, config: dict, path: Path, log) -> dict:
     """Synthesize and encode leaf by leaf (the program's offline
     compressor, ``core/store.compress_{array,stacked}``) into ``path``:
     one ``.npy`` per array and ``meta.json``.  One leaf's temporaries at a
@@ -144,7 +173,7 @@ def _write(cfg, config: dict, path: Path, log) -> dict:
     tmp.mkdir(parents=True)
     meta, synth_s, encode_s = {}, 0.0, 0.0
     t0 = time.perf_counter()
-    for i, names, arr in fp8_leaves(cfg, config["model"],
+    for i, names, arr in fp8_leaves(cfg, ref, config["model"],
                                     config["weight_seed"]):
         t1 = time.perf_counter()
         synth_s += t1 - t0
@@ -170,14 +199,14 @@ def _write(cfg, config: dict, path: Path, log) -> dict:
     return {"synth_s": synth_s, "encode_s": encode_s}
 
 
-def _load(path: Path, treedef, gains: dict):
+def _load(path: Path, treedef, params: dict):
     import jax
     from repro.core.store import CompressedMeta, CompressedTensor
     meta = json.loads((path / "meta.json").read_text())
     leaves = []
     for i in range(treedef.num_leaves):
-        if i in gains:
-            leaves.append(gains[i])
+        if i in params:
+            leaves.append(params[i])
             continue
         m = meta[str(i)]
         ct = dict(m["ct"], shape=tuple(m["ct"]["shape"]))
@@ -187,13 +216,13 @@ def _load(path: Path, treedef, gains: dict):
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
-def encoded_tree(cfg, config: dict, seed: int, log=print):
-    """The ECF8 tree with ``seed``'s norm gains (host arrays): the
+def encoded_tree(cfg, ref, config: dict, seed: int, log=print):
+    """The ECF8 tree with ``seed``'s 1-D parameters (host arrays): the
     matrices from the cache, or synthesized and encoded into it first.
     Returns (tree, info) where info records what set-up did."""
     import jax
     from repro.core.store import is_compressed
-    key = cache_key(config)
+    key = cache_key(config, ref)
     path = CACHE_DIR / key
     _, treedef = layout(cfg)
     info = {"cache": "hit", "synth_s": 0.0, "encode_s": 0.0}
@@ -202,11 +231,12 @@ def encoded_tree(cfg, config: dict, seed: int, log=print):
         CACHE_DIR.mkdir(parents=True, exist_ok=True)
         for old in CACHE_DIR.glob(f"{config['name']}-*"):
             shutil.rmtree(old, ignore_errors=True)   # a stale format
-        info.update(_write(cfg, config, path, log))
+        info.update(_write(cfg, ref, config, path, log))
         log(f"checkpoint: encoded {key} (synthesis {info['synth_s']:.2f} s, "
             f"encode {info['encode_s']:.2f} s)")
     t0 = time.perf_counter()
-    tree = _load(path, treedef, seeded_gains(cfg, seed))
+    tree = _load(path, treedef,
+                 seeded_params(cfg, ref, config["model"], seed))
     info["load_s"] = time.perf_counter() - t0
     n_ct = sum(is_compressed(x) for x in jax.tree_util.tree_leaves(
         tree, is_leaf=is_compressed))

@@ -12,10 +12,10 @@ import sys
 import time
 from dataclasses import dataclass, field
 
-from . import check, checkpoint, spec
+from . import check, checkpoint, spans, spec
 from .compile_clock import CompileClock
 from .loop import ClosedLoop, null_span
-from .traffic import Mix
+from .traffic import Mix, longest
 
 TRACE_DIR = spec.BENCH / ".trace"
 # the program's activation width per element (bfloat16 pages)
@@ -31,6 +31,7 @@ class Ctx:
     """What a run measured; the metric files read it."""
 
     model: dict
+    reference: object       # the configuration's bench/reference module
     peaks: dict
     chips: int
     max_batch: int
@@ -43,6 +44,7 @@ class Ctx:
     weight_leaves: list
     paged: object
     trace: dict | None = None
+    spans: dict | None = None   # lib/spans.read of the traced window
     kernels: dict = field(default_factory=dict)
 
     def kernel(self, name: str):
@@ -53,34 +55,38 @@ class Ctx:
 
 # -- the program, as the configuration states it ---------------------------
 
-MODEL_TO_ARCH = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-                 "num_attention_heads": "n_heads",
-                 "num_key_value_heads": "n_kv_heads", "head_dim": "hd",
-                 "num_hidden_layers": "n_layers", "vocab_size": "vocab_size",
-                 "tie_word_embeddings": "tie_embeddings",
-                 "rope_theta": "rope_theta", "mlp": "mlp_type",
-                 "qk_norm": "qk_norm"}
-# what the program computes without an option to do otherwise
-PROGRAM_FIXED = {"rms_norm_eps": 1e-6, "activation_dtype": "bfloat16"}
-
-
-def arch_config(config: dict):
+def arch_config(config: dict, ref, max_len: int):
     """The program's ArchConfig for ``config``: the registry entry with the
-    configuration's overrides, checked against every number of its
-    ``model`` section, so the program runs what the file states."""
+    configuration's overrides, checked against every key of its ``model``
+    section by the declaration of its reference ``ref``: a key the
+    program reads (``PROGRAM_FIELDS``), a key whose value the program
+    computes (``PROGRAM_FIXED``), the key of the longest sequence the
+    model takes (``PROGRAM_CONTEXT``), which the traffic's ``max_len``
+    must fit, and the program's layer as the reference's
+    (``PROGRAM_LAYER``).  So the program runs what the file states, and a
+    key no declaration covers is an error."""
     from repro.configs import get
     prog = config["program"]
     cfg = dataclasses.replace(get(prog["arch"]), **prog.get("overrides", {}))
     m = config["model"]
-    bad = [f"{k}: file {m[k]!r} vs program {getattr(cfg, a)!r}"
-           for k, a in MODEL_TO_ARCH.items() if m[k] != getattr(cfg, a)]
-    bad += [f"{k}: file {m[k]!r}, the program computes {v!r}"
-            for k, v in PROGRAM_FIXED.items() if m[k] != v]
-    if cfg.dtype != m["activation_dtype"]:
-        bad.append(f"dtype {cfg.dtype}")
-    if (set(cfg.pattern) != {"attn"} or cfg.n_experts or cfg.post_norms
-            or cfg.logit_softcap or cfg.attn_softcap or cfg.embed_scale):
-        bad.append("the program's layer is not the plain dense layer")
+    fields, fixed = ref.PROGRAM_FIELDS, ref.PROGRAM_FIXED
+    context = ref.PROGRAM_CONTEXT
+    uncovered = sorted(set(m) - set(fields) - set(fixed) - {context})
+    if uncovered:
+        raise ValueError(
+            f"configuration {config['name']}: model keys {uncovered} are "
+            f"in none of PROGRAM_FIELDS, PROGRAM_FIXED and PROGRAM_CONTEXT "
+            f"of its reference {config['reference']}")
+    bad = [f"{k}: file {m.get(k)!r} vs program {a} {getattr(cfg, a)!r}"
+           for k, a in fields.items() if m.get(k) != getattr(cfg, a)]
+    bad += [f"{k}: file {m.get(k)!r}, the program computes {v!r}"
+            for k, v in fixed.items() if m.get(k) != v]
+    if context in m and m[context] < max_len:
+        bad.append(f"{context}: file {m[context]!r}, the traffic asks for "
+                   f"{max_len} tokens")
+    bad += [f"program {a} {getattr(cfg, a)!r}, the reference's layer "
+            f"needs {v!r}" for a, v in ref.PROGRAM_LAYER.items()
+            if getattr(cfg, a) != v]
     if bad:
         raise ValueError(f"configuration {config['name']} and the program "
                          f"disagree: {'; '.join(bad)}")
@@ -136,15 +142,16 @@ def run(bm: dict, workload: str, *, seed: int, seconds: float, trace: bool,
     cell = spec.cell(bm, workload)
     config = spec.config(bm, cell["config"], root)
     traffic = spec.traffic(cell["traffic"], bench)
+    ref = spec.module("reference", config["reference"], bench)
     clock = CompileClock()
     log(f"cell {workload}: config {config['name']}, traffic "
         f"{cell['traffic']}, seed {seed}, {seconds} s, trace {int(trace)}; "
         f"compile cache {enable_compile_cache()}")
 
-    cfg = arch_config(config)
+    cfg = arch_config(config, ref, longest(traffic))
     mix = Mix(traffic, seed, cfg.vocab_size)
     ecfg = engine_config(config, mix, cfg)
-    tree, info = checkpoint.encoded_tree(cfg, config, seed, log=log)
+    tree, info = checkpoint.encoded_tree(cfg, ref, config, seed, log=log)
     t0 = time.perf_counter()
     params = jax.block_until_ready(jax.device_put(tree, device))
     del tree
@@ -178,7 +185,8 @@ def run(bm: dict, workload: str, *, seed: int, seconds: float, trace: bool,
         f"programs traced in the window {clock.traces - traces0}, "
         f"compiled {clock.compiles - compiles0}")
 
-    ctx = Ctx(model=config["model"], peaks=peaks, chips=cell["chips"],
+    ctx = Ctx(model=config["model"], reference=ref, peaks=peaks,
+              chips=cell["chips"],
               max_batch=ecfg.max_batch, loop=loop, w0=w0, w1=w1,
               setup_s=setup_s, setup_compile_s=setup_compile_s,
               hbm_peak_bytes=hbm, weight_leaves=weight_leaves(eng.params),
@@ -188,6 +196,7 @@ def run(bm: dict, workload: str, *, seed: int, seconds: float, trace: bool,
         from . import trace as T
         t0 = time.perf_counter()
         ctx.trace = T.reduce(*T.read(str(tdir)))
+        ctx.spans = spans.read(str(tdir))
         shutil.rmtree(tdir, ignore_errors=True)
         log(f"trace reduced in {time.perf_counter() - t0:.2f} s")
     metrics = {}
@@ -209,8 +218,9 @@ def run(bm: dict, workload: str, *, seed: int, seconds: float, trace: bool,
     records = [(r.prompt, list(r.req.out_tokens)) for r in loop.records]
     _delete((eng.cache, eng.last_tok, eng.last_logits))
     del eng
-    result["correct"], checked, ctrl = correct(config, traffic, records,
-                                               seed, params, cfg, control)
+    result["correct"], checked, ctrl = correct(config, ref, traffic,
+                                               records, seed, params, cfg,
+                                               control)
     if control:
         result["control"] = ctrl
     result["metrics"] = metrics
@@ -234,17 +244,17 @@ def _delete(tree) -> None:
             x.delete()
 
 
-def correct(config: dict, traffic: dict, records, seed: int, params, cfg,
-            control: bool = False) -> tuple:
+def correct(config: dict, ref, traffic: dict, records, seed: int, params,
+            cfg, control: bool = False) -> tuple:
     """(correct, {name: {value, limit}}, control) for the served tokens
-    and the served weights; ``params`` are freed here.  With ``control``
+    against the reference ``ref`` and the served weights; ``params`` are
+    freed here.  With ``control``
     the third item is the control's widest gap and its own verdict by the
     same limit, else None."""
     t0 = time.perf_counter()
     samples = check.sample(records, seed, traffic["check_requests"])
-    ref = spec.module("reference", config["reference"])
-    weights = check.ReferenceWeights(config["model"], config["weight_seed"],
-                                     seed)
+    weights = check.ReferenceWeights(ref, config["model"],
+                                     config["weight_seed"], seed)
     lay, _ = checkpoint.layout(cfg)
     bad_bits = check.weight_mismatches(params, lay, weights)
     _delete(params)

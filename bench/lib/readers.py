@@ -34,11 +34,12 @@ def p95(samples):
 
 def required_flops(ctx) -> int:
     m = ctx.model
+    mm = flops.layer_matmul(ctx.reference, m)
     total = 0
     for s in window_steps(ctx):
-        total += sum(flops.tokens(m, p, 1, logits=1)
+        total += sum(flops.tokens(m, mm, p, 1, logits=1)
                      for p in s.decode_tokens)
-        total += sum(flops.tokens(m, a, n) for a, n in s.prefill)
+        total += sum(flops.tokens(m, mm, a, n) for a, n in s.prefill)
         total += s.finished_prompts * flops.unembed(m)
     return total
 

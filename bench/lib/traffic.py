@@ -7,7 +7,11 @@ request as soon as the previous one finished.  Requests come in rounds of
 drawn from the mix's own ``shape_seed``, so every run sees the same sizes,
 and ``--seed`` only decides which client gets which size of a round and
 the token ids of every prompt.  Runs with different seeds therefore do
-the same amount of work, in another order and on other tokens.
+the same amount of work, in another order and on other tokens.  Round 0,
+the fill that set-up prefills in one go, keeps its drawn order for every
+seed: that order sets the set-up's prefill chunks, and with them the
+device's peak memory and which decode steps code pages, so it is part of
+the work and not of its order.
 
 Length distributions (``prompt_len`` / ``output_len``):
 
@@ -60,6 +64,11 @@ def draw_lengths(spec: dict, rng: np.random.Generator, n: int) -> np.ndarray:
     return np.clip(x, lo, hi).astype(np.int64)
 
 
+def longest(traffic: dict) -> int:
+    """Longest sequence (prompt + output) the mix can ask for."""
+    return int(traffic["prompt_len"]["max"]) + int(traffic["output_len"]["max"])
+
+
 class Mix:
     """Deals the requests of one traffic mix for one ``--seed``."""
 
@@ -75,21 +84,21 @@ class Mix:
 
     @property
     def max_len(self) -> int:
-        """Longest sequence (prompt + output) the mix can ask for."""
-        return (int(self.traffic["prompt_len"]["max"])
-                + int(self.traffic["output_len"]["max"]))
+        return longest(self.traffic)
 
     def round_sizes(self, j: int) -> list:
         """The (prompt_len, max_new) pairs of round ``j`` in client order:
-        sizes from ``shape_seed``, their order from ``--seed``."""
+        sizes from ``shape_seed``; their order as drawn in round 0, from
+        ``--seed`` in every later round."""
         if j not in self._rounds:
             t = self.traffic
             g = np.random.default_rng([int(t["shape_seed"]), j])
             plen = draw_lengths(t["prompt_len"], g, self.clients)
             nout = draw_lengths(t["output_len"], g, self.clients)
-            order = np.random.default_rng(
-                seed_words(self.seed) + [j, 0x5EED]).permutation(
-                    self.clients)
+            order = np.arange(self.clients) if j == 0 else \
+                np.random.default_rng(
+                    seed_words(self.seed) + [j, 0x5EED]).permutation(
+                        self.clients)
             self._rounds[j] = [(int(plen[k]), int(nout[k])) for k in order]
         return self._rounds[j]
 

@@ -1,25 +1,23 @@
-"""The synthetic FP8 checkpoint: names, shapes, values and per-seed gains.
+"""The synthetic FP8 checkpoint: values of matrices and per-seed 1-D
+parameters.
 
-Nothing here imports the program.  Both the checkpoint loader
+Nothing here imports the program, and nothing here names a tensor: the
+configuration's reference (``bench/reference/<reference>.py``) lists
+them, ``tensor_specs(model)`` the fp8 matrices and ``param_specs(model)``
+the 1-D parameters with their kind.  Both the checkpoint loader
 (``lib/checkpoint.py``) and the plain reference read weights through this
 module, so they agree on every value by construction.
 
-* A configuration's ``model`` section fixes the tensors: ``embed``
-  (vocab, d), ``unembed`` (d, vocab) when untied, and per layer ``l``
-  ``layer{l}.attn.w{q,k,v,o}`` and ``layer{l}.mlp.*`` in the ``x @ W``
-  orientation (input rows, output columns).
-* Base values: normal with a 1/sqrt(fan-in) scale (1/sqrt(d) for the
-  embedding), cast to float8_e4m3fn, drawn from the configuration's
-  ``weight_seed`` in row blocks that each have their own generator, so a
-  value depends on (seed, name, position) only.
-* The norm gains (:func:`gain_specs`: per layer ``layer{l}.norm1``
-  before attention, ``layer{l}.norm2`` before the MLP, with QK-norm
-  ``layer{l}.attn.q_norm`` and ``layer{l}.attn.k_norm``; then
-  ``final_norm``) come from ``--seed`` (:func:`gains`): every channel a
-  sign and a magnitude of 0.75, 1 or 1.25.  So the model differs from
-  seed to seed while the fp8 matrices, and so the encoded checkpoint,
-  stay those of the base checkpoint.  Every gain and every gain less one
-  is exact in bfloat16.
+* Matrices (:func:`synth`): normal with a 1/sqrt(fan-in) scale
+  (1/sqrt(d) for the embedding), cast to float8_e4m3fn, drawn from the
+  configuration's ``weight_seed`` in row blocks that each have their own
+  generator, so a value depends on (seed, name, position) only.
+* 1-D parameters (:func:`vector`) come from ``--seed``: a ``gain``
+  (:func:`gains`) is per channel a sign and a magnitude of 0.75, 1 or
+  1.25, a ``bias`` (:func:`biases`) one of -0.25, 0 and 0.25.  So the
+  model differs from seed to seed while the fp8 matrices, and so the
+  encoded checkpoint, stay those of the base checkpoint.  Every gain,
+  every gain less one and every bias is exact in bfloat16.
 """
 from __future__ import annotations
 
@@ -34,31 +32,9 @@ BLOCK_ELEMS = 1 << 22
 _THREADS = 8
 
 
-
 def fp8_dtype():
     import ml_dtypes
     return np.dtype(getattr(ml_dtypes, FP8))
-
-
-def tensor_specs(model: dict) -> list:
-    """[(name, shape)] of every weight matrix of a dense decoder LM with
-    a SwiGLU MLP."""
-    d, ff = model["hidden_size"], model["intermediate_size"]
-    hd = model["head_dim"]
-    hq, hkv = model["num_attention_heads"], model["num_key_value_heads"]
-    V = model["vocab_size"]
-    specs = [("embed", (V, d))]
-    if not model["tie_word_embeddings"]:
-        specs.append(("unembed", (d, V)))
-    for l in range(model["num_hidden_layers"]):
-        p = f"layer{l}."
-        specs += [(p + "attn.wq", (d, hq * hd)),
-                  (p + "attn.wk", (d, hkv * hd)),
-                  (p + "attn.wv", (d, hkv * hd)),
-                  (p + "attn.wo", (hq * hd, d))]
-        specs += [(p + "mlp.wi_gate", (d, ff)), (p + "mlp.wi_up", (d, ff)),
-                  (p + "mlp.wo", (ff, d))]
-    return specs
 
 
 def name_crc(name: str) -> int:
@@ -99,21 +75,43 @@ def synth(specs, weight_seed: int, out=None) -> dict:
 GAIN_MAGNITUDES = (0.75, 1.0, 1.25)
 
 
-def gain_specs(model: dict) -> list:
-    """[(name, length)] of every norm gain of a dense decoder LM."""
-    d, hd = model["hidden_size"], model["head_dim"]
-    specs = []
-    for l in range(model["num_hidden_layers"]):
-        p = f"layer{l}."
-        specs += [(p + "norm1", d), (p + "norm2", d)]
-        if model["qk_norm"]:
-            specs += [(p + "attn.q_norm", hd), (p + "attn.k_norm", hd)]
-    return specs + [("final_norm", d)]
-
-
 def gains(seed: int, name: str, n: int) -> np.ndarray:
     """(n,) float32 gains of ``name`` under run seed ``seed``."""
     from .traffic import seed_words
     g = np.random.default_rng(seed_words(seed) + [name_crc(name)])
     mag = np.asarray(GAIN_MAGNITUDES, np.float32)[g.integers(0, 3, n)]
     return np.where(g.integers(0, 2, n) == 1, -mag, mag)
+
+
+BIAS_VALUES = (-0.25, 0.0, 0.25)
+
+
+def biases(seed: int, name: str, n: int) -> np.ndarray:
+    """(n,) float32 biases of ``name`` under run seed ``seed``."""
+    from .traffic import seed_words
+    g = np.random.default_rng(seed_words(seed) + [name_crc(name)])
+    return np.asarray(BIAS_VALUES, np.float32)[g.integers(0, 3, n)]
+
+
+# kind of 1-D parameter -> (how it is drawn, the form the program stores
+# it in): a norm's gain as its scale less one (the program's norms
+# multiply by ``1 + scale``), a bias as drawn
+KINDS = {"gain": (gains, lambda g: g - 1), "bias": (biases, lambda b: b)}
+
+
+def _kind(name: str, kind: str) -> tuple:
+    if kind not in KINDS:
+        raise ValueError(f"parameter {name}: unknown kind {kind!r}, "
+                         f"known: {sorted(KINDS)}")
+    return KINDS[kind]
+
+
+def vector(seed: int, name: str, n: int, kind: str) -> np.ndarray:
+    """(n,) float32 values of the 1-D parameter ``name`` of ``kind``
+    (a key of ``KINDS``) under run seed ``seed``."""
+    return _kind(name, kind)[0](seed, name, n)
+
+
+def program_form(seed: int, name: str, n: int, kind: str) -> np.ndarray:
+    """:func:`vector` in the form the program stores a ``kind``."""
+    return _kind(name, kind)[1](vector(seed, name, n, kind))

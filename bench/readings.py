@@ -11,7 +11,9 @@ program's widest gap and the control's (the reference with fp8 operands,
 own runs never run the control.  One JSON line per seed, then a summary
 line: the program's largest reading (the lower end of the limit) and the
 control's smallest (the upper end).  It exits 1 where the control came
-out correct on any seed.
+out correct on any seed.  A process's peak memory never falls, so from
+the second seed on ``hbm_peak_gb`` holds the earlier seeds' checks too:
+only ``bench/run.py``, one run a process, reads it.
 """
 import time
 

@@ -1,4 +1,10 @@
-"""Plain reference of a dense decoder-only LM (pre-norm, GQA, RoPE).
+"""Plain reference of a dense decoder-only LM (pre-norm, GQA, RoPE), and
+the declaration of its layer: the checkpoint's tensors
+(:func:`tensor_specs`, :func:`param_specs`) and what the program has to
+run for it (``PROGRAM_FIELDS``, ``PROGRAM_FIXED``, ``PROGRAM_CONTEXT``,
+``PROGRAM_LAYER``).
+``bench/lib`` reads the layer from here and knows no tensor, norm or MLP
+kind of its own.
 
 Written from the model descriptions, not from the program: straight
 ``jax.numpy`` in float32 at ``highest`` matmul precision, no kernels, no
@@ -12,6 +18,8 @@ whole head, the two halves rotated as pairs; causal softmax attention
 with ``1/sqrt(head_dim)`` scaling, ``g = heads / kv_heads`` query heads
 per key/value head; MLP ``swiglu`` (``silu(x Wg) * (x Wu) Wo``); a final
 RMSNorm times its gain before the (tied or untied) unembedding.
+:func:`forward` takes the MLP as an argument, so a reference whose layer
+differs only there reuses the rest.
 
 ``precision="fp8"`` is the control: every matmul operand that is not a
 weight (the weights are fp8 already) is rounded to float8_e4m3fn first.
@@ -27,6 +35,71 @@ import jax.numpy as jnp
 F32 = jnp.float32
 Q_BLOCK = 512          # query rows per attention block; sequences pad to it
 VOCAB_BLOCK = 16384    # unembedding columns per block
+
+
+# -- the layer's declaration ---------------------------------------------------
+
+def tensor_specs(model: dict) -> list:
+    """[(name, shape)] of every weight matrix of a dense decoder LM with
+    a SwiGLU MLP, in the ``x @ W`` orientation (input rows, output
+    columns)."""
+    d, ff = model["hidden_size"], model["intermediate_size"]
+    hd = model["head_dim"]
+    hq, hkv = model["num_attention_heads"], model["num_key_value_heads"]
+    V = model["vocab_size"]
+    specs = [("embed", (V, d))]
+    if not model["tie_word_embeddings"]:
+        specs.append(("unembed", (d, V)))
+    for l in range(model["num_hidden_layers"]):
+        p = f"layer{l}."
+        specs += [(p + "attn.wq", (d, hq * hd)),
+                  (p + "attn.wk", (d, hkv * hd)),
+                  (p + "attn.wv", (d, hkv * hd)),
+                  (p + "attn.wo", (hq * hd, d))]
+        specs += [(p + "mlp.wi_gate", (d, ff)), (p + "mlp.wi_up", (d, ff)),
+                  (p + "mlp.wo", (ff, d))]
+    return specs
+
+
+def param_specs(model: dict) -> list:
+    """[(name, length, kind)] of every 1-D parameter: the RMSNorm gains
+    (kind ``gain``), per layer ``norm1`` before attention and ``norm2``
+    before the MLP, with QK-norm ``attn.q_norm`` and ``attn.k_norm``;
+    then ``final_norm``."""
+    d, hd = model["hidden_size"], model["head_dim"]
+    specs = []
+    for l in range(model["num_hidden_layers"]):
+        p = f"layer{l}."
+        specs += [(p + "norm1", d), (p + "norm2", d)]
+        if model["qk_norm"]:
+            specs += [(p + "attn.q_norm", hd), (p + "attn.k_norm", hd)]
+    return [(n, k, "gain") for n, k in specs + [("final_norm", d)]]
+
+
+# ``model`` key -> the program's ``ArchConfig`` attribute that must equal it
+PROGRAM_FIELDS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
+                  "num_attention_heads": "n_heads",
+                  "num_key_value_heads": "n_kv_heads", "head_dim": "hd",
+                  "num_hidden_layers": "n_layers",
+                  "vocab_size": "vocab_size",
+                  "tie_word_embeddings": "tie_embeddings",
+                  "rope_theta": "rope_theta", "mlp": "mlp_type",
+                  "qk_norm": "qk_norm", "activation_dtype": "dtype"}
+# ``model`` key -> what the program computes with no option to do
+# otherwise: RMSNorm at 1e-6, SiLU in the gate
+PROGRAM_FIXED = {"rms_norm_eps": 1e-6, "hidden_act": "silu"}
+# ``model`` key of the longest sequence the model takes: the program
+# computes rotary angles at every position (no table to outgrow), so
+# only the traffic's longest sequence is held to it
+PROGRAM_CONTEXT = "max_position_embeddings"
+# ``ArchConfig`` attribute -> the value this layer needs: one attention
+# layer repeated, no experts, post-norms, softcaps or embedding scale
+PROGRAM_LAYER = {"pattern": ("attn",), "n_experts": 0, "post_norms": False,
+                 "logit_softcap": 0.0, "attn_softcap": 0.0,
+                 "embed_scale": False}
+
+
+# -- the forward pass ------------------------------------------------------------
 
 
 def _round(x, precision: str):
@@ -78,8 +151,15 @@ def _attention(q, k, v, precision):
     return o.reshape(T, hq, hd)
 
 
-@partial(jax.jit, static_argnames=("dims", "precision"))
-def _layer(x, pos, w, dims, precision):
+def swiglu(h, w, r):
+    """``silu(h Wg) * (h Wu) Wo``; ``r`` rounds a matmul operand."""
+    a = jax.nn.silu(h @ _weight(w["mlp.wi_gate"])) * (
+        h @ _weight(w["mlp.wi_up"]))
+    return r(a) @ _weight(w["mlp.wo"])
+
+
+@partial(jax.jit, static_argnames=("dims", "mlp", "precision"))
+def _layer(x, pos, w, dims, mlp, precision):
     hq, hkv, hd, qk_norm, theta, eps = dims
     T = x.shape[0]
     r = partial(_round, precision=precision)
@@ -94,9 +174,7 @@ def _layer(x, pos, w, dims, precision):
     o = _attention(q, k, v, precision).reshape(T, hq * hd)
     x = x + r(o) @ _weight(w["attn.wo"])
     h = r(rms_norm(x, eps, w["norm2"]))
-    a = jax.nn.silu(h @ _weight(w["mlp.wi_gate"])) * (
-        h @ _weight(w["mlp.wi_up"]))
-    return x + r(a) @ _weight(w["mlp.wo"])
+    return x + mlp(h, w, r)
 
 
 @partial(jax.jit, static_argnames=("tied", "precision"))
@@ -113,12 +191,19 @@ def _embed_rows(w8, tokens):
 
 def logits(model: dict, weights, tokens, sel, precision: str = "float32"):
     """Logits (len(sel), vocab) float32 of the sequence ``tokens`` at
-    positions ``sel``.  ``weights(name)`` gives the fp8 array on the
-    device of every tensor of ``lib/weights.tensor_specs`` and
-    ``weights.gain(name)`` the gain of every norm of
-    ``lib/weights.gain_specs``."""
+    positions ``sel``, with the SwiGLU MLP."""
     if model["mlp"] != "swiglu":
         raise ValueError(f"unknown mlp {model['mlp']!r}")
+    return forward(model, weights, tokens, sel, precision, swiglu)
+
+
+def forward(model: dict, weights, tokens, sel, precision: str, mlp):
+    """:func:`logits` with the MLP ``mlp(h, w, r)`` of a layer whose
+    tensors and parameters ``w`` holds by their names after
+    ``layer{l}.``.  ``weights(name)`` gives the fp8 array on the device of
+    every tensor of the reference's ``tensor_specs``,
+    ``weights.param(name)`` every parameter of its ``param_specs`` and
+    ``weights.layer(l)`` both of layer ``l``."""
     dims = (model["num_attention_heads"], model["num_key_value_heads"],
             model["head_dim"], bool(model["qk_norm"]),
             float(model["rope_theta"]), float(model["rms_norm_eps"]))
@@ -130,16 +215,10 @@ def logits(model: dict, weights, tokens, sel, precision: str = "float32"):
         x = _embed_rows(weights("embed"), jnp.asarray(toks))
         pos = jnp.arange(Tp)
         for l in range(model["num_hidden_layers"]):
-            w = {n: weights(f"layer{l}.{n}")
-                 for n in ("attn.wq", "attn.wk", "attn.wv", "attn.wo",
-                           "mlp.wi_gate", "mlp.wi_up", "mlp.wo")}
-            w.update({n: weights.gain(f"layer{l}.{n}")
-                      for n in ("norm1", "norm2") + (
-                          ("attn.q_norm", "attn.k_norm")
-                          if model["qk_norm"] else ())})
-            x = _layer(x, pos, w, dims=dims, precision=precision)
+            x = _layer(x, pos, weights.layer(l), dims=dims, mlp=mlp,
+                       precision=precision)
         h = rms_norm(x[jnp.asarray(np.asarray(sel, np.int32))],
-                     dims[-1], weights.gain("final_norm"))
+                     dims[-1], weights.param("final_norm"))
         tied = bool(model["tie_word_embeddings"])
         w8 = weights("embed" if tied else "unembed")
         V = model["vocab_size"]

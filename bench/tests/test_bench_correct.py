@@ -1,8 +1,9 @@
-"""``correct`` end to end on the CPU at test widths: a sound run passes;
-the run fails with a token altered where it is produced, with a decode
-step that returns its state unchanged and with a weight decode that errs
-in one bit, and the control (the reference in fp8) is judged not
-correct."""
+"""``correct`` end to end on the CPU at test widths: a sound run passes,
+also of a layer that only its reference file declares; the run fails
+with a token altered where it is produced, with a decode step that
+returns its state unchanged and with a weight decode that errs in one
+bit, and the control (the reference in fp8) is judged not correct.  A
+traced run reports the program's span readings."""
 import os
 import sys
 import time
@@ -26,14 +27,15 @@ PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 def _fresh_caches(tmp_path, monkeypatch):
     from repro.serving import engine as E
     monkeypatch.setattr(checkpoint, "CACHE_DIR", tmp_path / "ckpt")
+    monkeypatch.setattr(harness, "TRACE_DIR", tmp_path / "trace")
     monkeypatch.setattr(E, "_STEP_CACHE", {})
     monkeypatch.setattr(E, "_CHUNK_CACHE", {})
 
 
-def _run(seed=11, trace=False, control=False):
+def _run(seed=11, trace=False, control=False, workload="tiny.mix"):
     import jax
     bm = spec.load_benchmark(DATA)
-    return harness.run(bm, "tiny.mix", seed=seed, seconds=1.0, trace=trace,
+    return harness.run(bm, workload, seed=seed, seconds=1.0, trace=trace,
                        t_start=time.perf_counter(), device=jax.devices()[0],
                        peaks=PEAKS, root=DATA, bench=DATA, control=control)
 
@@ -51,6 +53,27 @@ def test_sound_run_is_correct_and_reports_its_metrics():
     # the control, on the same served sequences, is not correct
     assert r["control"]["correct"] is False, r["control"]
     assert r["control"]["logit_gap"] > gap["limit"]
+
+
+def test_a_layer_bench_lib_does_not_know_is_correct():
+    """A squared-ReLU MLP (``mlp.wi``, ``mlp.wo``), declared only in the
+    fixture's reference file, runs through the harness and is correct;
+    its control is not."""
+    r = _run(seed=12, control=True, workload="tiny-sqrelu.mix")
+    assert r["correct"], r["check"]
+    assert r["check"]["weight_bit_mismatches"] == {"value": 0, "limit": 0}
+    assert r["metrics"]["out_tok_s"]["value"] > 0
+    assert r["control"]["correct"] is False, r["control"]
+
+
+def test_traced_run_reads_the_program_spans():
+    r = _run(seed=13, trace=True)
+    assert r["correct"], r["check"]
+    m = {k: v["value"] for k, v in r["metrics"].items()}
+    for name in ("engine.step_ms.decode", "engine.device_wait_ms.decode",
+                 "engine.host_ms.decode", "kv.code_ms.decode"):
+        assert m[name] >= 0, name
+    assert 0 < m["engine.step_ms.decode"]
 
 
 def test_altered_token_is_not_correct(monkeypatch):

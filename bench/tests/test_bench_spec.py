@@ -4,15 +4,19 @@ import re
 import subprocess
 import sys
 
+import pytest
+
 # bench/run.py's import paths: the program's src/ and bench/ itself
 _BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for _p in (os.path.join(os.path.dirname(_BENCH), "src"), _BENCH):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from lib import harness, spec
+from lib import flops, harness, spec
+from lib.traffic import longest
 
 BENCH = spec.BENCH
+DATA = BENCH / "tests" / "data"
 
 
 def test_every_entry_has_its_files():
@@ -20,11 +24,12 @@ def test_every_entry_has_its_files():
     for c in bm["configs"]:
         cfg = spec.config(bm, c["name"])
         assert cfg["name"] == c["name"]
-        assert (BENCH / "reference" / f"{cfg['reference']}.py").exists()
-        harness.arch_config(cfg)        # the program runs what it states
     for w in bm["workloads"]:
         assert spec.cell(bm, w["name"]) is w
-        spec.traffic(w["traffic"])
+        cfg = spec.config(bm, w["config"])
+        ref = spec.module("reference", cfg["reference"])
+        # the program runs what the file states, for the cell's traffic
+        harness.arch_config(cfg, ref, longest(spec.traffic(w["traffic"])))
     for m in bm["end_to_end"] + bm["per_layer"]:
         assert callable(spec.module("metrics", m["name"]).read)
     for k in ("ecf8_decode", "page_decode"):
@@ -39,6 +44,21 @@ def test_metric_lists_follow_the_cells():
         assert "setup_s" in e2e and len(e2e) >= 2
         per = spec.metrics(bm, w["name"], trace=True)
         assert per and all(m["moves"] in e2e for m in per)
+
+
+@pytest.mark.parametrize("root, bench, name, mlp_matrices", [
+    (spec.ROOT, BENCH, "qwen3-8b", 3), (DATA, DATA, "tiny-sqrelu", 2)])
+def test_layer_flops_count_the_reference_matrices(root, bench, name,
+                                                  mlp_matrices):
+    """Two FLOPs for each element of every layer matrix: four of
+    attention and the MLP's (SwiGLU three, squared ReLU two)."""
+    cfg = spec.config(spec.load_benchmark(root), name, root)
+    m = cfg["model"]
+    d, hd, ff = m["hidden_size"], m["head_dim"], m["intermediate_size"]
+    hq, hkv = m["num_attention_heads"], m["num_key_value_heads"]
+    one = 2 * (d * hq * hd * 2 + d * hkv * hd * 2 + mlp_matrices * d * ff)
+    ref = spec.module("reference", cfg["reference"], bench)
+    assert flops.layer_matmul(ref, m) == m["num_hidden_layers"] * one
 
 
 def test_harness_code_names_no_cell():

@@ -35,6 +35,16 @@ def test_other_seed_same_sizes_other_tokens():
     assert [r.prompt for r in a] != [r.prompt for r in b]
 
 
+def test_fill_round_has_one_order_for_every_seed():
+    """Round 0 (set-up's fill) deals its sizes in the drawn order whatever
+    the seed; later rounds in the seed's order."""
+    order = lambda seed, j: [(len(r.prompt), r.max_new)
+                             for r in _requests(seed) if r.index == j]
+    seeds = (7, 8, 2 ** 31 + 12345)
+    assert len({tuple(order(s, 0)) for s in seeds}) == 1
+    assert any(order(7, j) != order(8, j) for j in (1, 2, 3))
+
+
 def test_lengths_within_bounds():
     for r in _requests(3, rounds=20):
         assert TRAFFIC["prompt_len"]["min"] <= len(r.prompt) <= \
